@@ -53,7 +53,7 @@ class SpecInfo:
 
     @property
     def is_any_space(self) -> bool:
-        """True for ``TPUMemorySpace.ANY`` specs: the array stays in
+        """True for ``MemorySpace.ANY`` specs: the array stays in
         HBM/host and the BlockSpec pipeline never stages it through
         VMEM (the kernel DMAs slices itself) — such inputs must not be
         priced against the VMEM block budget."""

@@ -79,7 +79,9 @@ from repro.core import (DIVERGED_NONFINITE, GuardSpec, KernelConfig,
 from repro.core import distributed
 from repro.core.nystrom import (LANDMARK_METHODS, fit_nystrom,
                                 lowrank_operator)
-from repro.core.perf_model import choose_recompute_every, modeled_fit_cost
+from repro.core.perf_model import (VMEM_BYTES, choose_recompute_every,
+                                   modeled_fit_cost, stream_chunk_fits,
+                                   stream_working_set_bytes)
 from repro.core.predict import BatchedPredictor, validate_queries
 from repro.resilience.guard import (DivergenceError, finite_health,
                                     init_residual, make_correct_fn,
@@ -535,6 +537,25 @@ def _guarded_serial_chunk(A, y, a0, f0, schedule, tol, fault_round,
                       guard=spec, marks=marks)
 
 
+# Backends whose compiler cannot build the f64 rung: XLA:TPU implements
+# LU decomposition for F32/C64 only, so the K-RR block solve in f64 is
+# refused at compile time (seen on a TPU v5e with jax 0.9.0).
+F64_UNSUPPORTED_PLATFORMS = ("tpu",)
+
+
+def _require_f64(x, events) -> None:
+    """Stop the ladder with an error naming the device when the f64 rung
+    cannot run where ``x`` lives, instead of failing in the compiler."""
+    dev = next(iter(x.devices()))
+    if dev.platform in F64_UNSUPPORTED_PLATFORMS:
+        raise DivergenceError(
+            f"guarded solve diverged and the escalation ladder reached its "
+            f"f64 rung, which {dev.platform} device {dev.device_kind!r} "
+            f"cannot compile (no f64 linear solve on this backend); rerun "
+            f"with a smaller s, a larger regularization, or on a CPU",
+            events=tuple(events))
+
+
 def _cast_floating(tree, dtype):
     """Cast every floating leaf (operators are registered pytrees, so
     their static config rides along untouched)."""
@@ -663,6 +684,7 @@ def _run_guarded_serial(problem, A_s, y, a0, schedule, cfg_s,
                         action=action, kind=kind)
                 tel.mark("fallback", phase="guard")
             if x64_new and not x64:
+                _require_f64(A_cur, events)
                 x64 = True
                 with enable_x64():
                     A_cur = A_cur.astype(jnp.float64)
@@ -817,6 +839,7 @@ def _run_guarded_dist(problem, A_s, y, a0, schedule, cfg_s,
                         action=action, kind=kind)
                 tel.mark("fallback", phase="guard")
             if x64_new and not x64:
+                _require_f64(A_cur, events)
                 x64 = True
                 with enable_x64():
                     A_cur = A_cur.astype(jnp.float64)
@@ -925,6 +948,17 @@ def _build_representation(A, cfg, opts: SolverOptions):
                                  'resolves it via repro.tune.autotune.'
                                  'resolve_options before building the '
                                  'representation')
+            m, n = A.shape
+            sb = opts.s_eff * (opts.b if isinstance(cfg, KRRConfig) else 1)
+            cr = min(int(opts.stream), m)
+            word = A.dtype.itemsize
+            if not stream_chunk_fits(cr, n, sb, word=word):
+                raise ValueError(
+                    f"stream={opts.stream} does not fit the VMEM budget of "
+                    f"{VMEM_BYTES} bytes at n={n}, s*b={sb}: the streamed "
+                    f"KMV's double-buffered working set is "
+                    f"{stream_working_set_bytes(cr, n, sb, word=word)} "
+                    f"bytes; pin a smaller chunk or use stream='auto'")
             return (StreamingGramOperator.from_dense(
                 A, cfg.kernel, chunk_rows=int(opts.stream)), A)
         return ExactGramOperator(A, cfg.kernel), A

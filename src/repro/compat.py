@@ -1,63 +1,37 @@
-"""Version-compatibility shims for jax APIs that moved between releases.
+"""Thin wrappers over the jax 0.9 APIs the repo calls from many sites.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` (0.4.x, with
-``check_rep``/``axis_names``-less signature) to ``jax.shard_map`` (with
-``check_vma``/``axis_names``); ``enable_x64``, ``CompilerParams``,
-``cost_analysis`` and mesh ``axis_types`` similarly renamed or reshaped.
-Call sites use these wrappers so the repo runs on both sides.
+The repo pins ``jax==0.9.0`` (``pyproject.toml``); these names keep the
+call sites short and give each API one place to change on the next pin.
 """
 from __future__ import annotations
 
 import jax
 from jax.experimental.pallas import tpu as _pltpu
 
-# Pallas compiler params: pltpu.TPUCompilerParams (0.4.x) -> CompilerParams
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")
+CompilerParams = _pltpu.CompilerParams
 
 
 def enable_x64(flag: bool = True):
-    """``jax.experimental.enable_x64`` (0.4.x) / ``jax.enable_x64``."""
-    try:
-        from jax.experimental import enable_x64 as ctx
-    except ImportError:
-        ctx = jax.enable_x64
-    return ctx(flag)
+    """Context manager turning 64-bit types on (or off) for its body."""
+    return jax.enable_x64(flag)
 
 
 def make_mesh_auto(axis_shapes, axis_names):
-    """``jax.make_mesh`` with every axis explicitly Auto on jax versions
-    that have ``jax.sharding.AxisType``; 0.4.x has neither the kwarg nor
-    any non-Auto behavior, so the plain call is equivalent."""
-    AxisType = getattr(jax.sharding, "AxisType", None)
-    if AxisType is None:
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis explicitly ``AxisType.Auto``."""
     return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=(AxisType.Auto,) * len(axis_names))
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returned a one-element list of dicts
-    on 0.4.x and a plain dict on newer jax; normalize to the dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a dict (``{}`` when XLA has none)."""
+    return compiled.cost_analysis() or {}
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False,
               axis_names=None):
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # Partial-auto (auto = axes not in axis_names) hits an XLA
-    # IsManualSubgroup check-failure on 0.4.x CPU builds, so fall back to
-    # fully-manual.  Safe for our call sites: their bodies only issue
-    # collectives over the manual axes, so the auto axes merely lose the
-    # GSPMD sharding hint and compute replicated — same values.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    kw = {"check_vma": check_vma}
+    if axis_names is not None:
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

@@ -40,11 +40,26 @@ class KRRConfig:
 
 def block_schedule(key: jax.Array, H: int, m: int, b: int) -> jnp.ndarray:
     """(H, b) coordinate blocks, each sampled uniformly WITHOUT replacement
-    (paper Alg. 3 line 4). Shared by BDCD and s-step BDCD."""
+    (paper Alg. 3 line 4). Shared by BDCD and s-step BDCD.
+
+    Each block is drawn by Floyd's algorithm in O(b^2) work: step j draws
+    t uniform on [0, m-b+j] and keeps it, or m-b+j if t is already taken,
+    which makes every b-subset equally likely.  ``jax.random.choice``
+    would permute all m indices per block, an (H, m) sort that does not
+    fit a chip's memory at m=2^18, H=4096."""
+    if not 1 <= b <= m:
+        raise ValueError(f"block size b={b} must be in [1, m={m}]")
     keys = jax.random.split(key, H)
+    tops = jnp.arange(m - b, m)
 
     def one(k):
-        return jax.random.choice(k, m, (b,), replace=False)
+        ts = jax.random.randint(k, (b,), 0, tops + 1)
+
+        def pick(j, out):
+            t = ts[j]
+            return out.at[j].set(jnp.where(jnp.any(out == t), tops[j], t))
+
+        return jax.lax.fori_loop(0, b, pick, jnp.full((b,), -1, ts.dtype))
 
     return jax.vmap(one)(keys)
 

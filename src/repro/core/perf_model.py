@@ -79,7 +79,7 @@ def sstep_bdcd_cost(prob: Problem, mach: Machine, P: int, s: int) -> dict:
 
 def best_s(prob: Problem, mach: Machine, P: int,
            candidates=(1, 2, 4, 8, 16, 32, 64, 128, 256),
-           hbm_bytes: int = 16 * 2 ** 30, word: int = 4,
+           hbm_bytes: int = None, word: int = 4,
            return_frontier: bool = False) -> tuple:
     """Offline tuning of s (paper 5.2.1): best predicted time among the
     FEASIBLE candidates — an s whose per-round KMV working set (the
@@ -93,6 +93,8 @@ def best_s(prob: Problem, mach: Machine, P: int,
     ones carry their modeled time too — the frontier shows what the
     memory ceiling cost us).
     """
+    if hbm_bytes is None:
+        hbm_bytes = device_memory_bytes()
     frontier = []
     for s in candidates:
         feasible = s == 1 or slab_fits_hbm(prob.m, s * prob.b,
@@ -476,11 +478,34 @@ def kmv_round_hbm_bytes(m: int, n: int, sb: int, c: int = 1,
     return word * (kmv + cross)
 
 
-def slab_fits_hbm(m: int, sb: int, hbm_bytes: int = 16 * 2 ** 30,
+HOST_MEMORY_BYTES = 16 * 2 ** 30   # budget modeled for a CPU backend,
+                                   # which reports no device limit
+
+
+def device_memory_bytes() -> int:
+    """Device-memory budget of the default device: the limit the device
+    reports itself (``memory_stats()["bytes_limit"]`` — a TPU does), or
+    ``HOST_MEMORY_BYTES`` on the CPU backend, which reports none.  An
+    accelerator that reports no limit is an error, not a guess."""
+    import jax
+    dev = jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    if "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if dev.platform == "cpu":
+        return HOST_MEMORY_BYTES
+    raise RuntimeError(f"{dev.platform} device {dev.device_kind!r} reports "
+                       f"no memory bytes_limit; pass the budget explicitly")
+
+
+def slab_fits_hbm(m: int, sb: int, hbm_bytes: int = None,
                   word: int = 4) -> bool:
     """Whether the materialized m x sb slab ALONE fits the HBM budget
     (A's own footprint is not counted, so this is an optimistic bound) —
-    the slab-free path has no such ceiling on m."""
+    the slab-free path has no such ceiling on m.  ``hbm_bytes`` defaults
+    to the device's own budget (``device_memory_bytes``)."""
+    if hbm_bytes is None:
+        hbm_bytes = device_memory_bytes()
     return word * m * sb < hbm_bytes
 
 
@@ -543,14 +568,27 @@ def stream_pipeline_cost(m: int, n: int, sb: int, chunk_rows: int,
                 overlap_speedup=unoverlapped / max(time, 1e-30))
 
 
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
 def stream_working_set_bytes(chunk_rows: int, n: int, sb: int, *,
                              c: int = 1, word: int = 4) -> int:
-    """On-chip bytes the streamed contraction keeps live: TWO slots of
-    the data chunk and of its right-hand-side chunk (double buffering),
-    the (sb x n) sampled rows, the transient (chunk_rows x sb) kernel
-    tile, and the (sb x c) accumulator."""
-    return word * (2 * chunk_rows * n + 2 * chunk_rows * c
-                   + sb * n + chunk_rows * sb + sb * c)
+    """VMEM bytes ``kernels/kmv_stream.py`` allocates, at the padded
+    shapes it allocates them: rows round up to the sublane tile (8 for
+    4-byte words, 16 for 2-byte), features and right-hand-side columns
+    to the 128-wide lane tile.  Live are TWO slots of the data chunk and
+    of its right-hand-side chunk (double buffering), the pipelined
+    (sb x n) sampled-row block and the (sb x c) output block (two
+    buffers each), the (sb x c) accumulator, and the transient
+    (chunk_rows x sb) kernel tile.  Checked against the v5e compiler at
+    n in {64, 256, 512}: every size this admits under the 16 MiB budget
+    compiles, every size it rejects was refused."""
+    sub = 16 if word == 2 else 8
+    cr, r = _round_up(chunk_rows, sub), _round_up(sb, sub)
+    n_, c_ = _round_up(n, 128), _round_up(c, 128)
+    return word * (2 * cr * n_ + 2 * cr * c_ + 2 * r * n_ + 3 * r * c_
+                   + cr * r)
 
 
 def stream_chunk_fits(chunk_rows: int, n: int, sb: int, *, c: int = 1,
@@ -567,12 +605,14 @@ def stream_chunk_fits(chunk_rows: int, n: int, sb: int, *, c: int = 1,
 
 
 def streaming_required(m: int, n: int, sb: int, *, c: int = 1,
-                       word: int = 4,
-                       device_bytes: int = 16 * 2 ** 30) -> bool:
+                       word: int = 4, device_bytes: int = None) -> bool:
     """Whether the RESIDENT slab-free round — X (m x n) plus the KMV
     round set (the x vector, the sampled rows, the contracted outputs) —
-    exceeds the device-memory budget: the gate between "fits in HBM"
-    and the streamed pipeline (ISSUE/ROADMAP's out-of-core axis)."""
+    exceeds the device-memory budget (default: the device's own,
+    ``device_memory_bytes``): the gate between "fits in HBM" and the
+    streamed pipeline."""
+    if device_bytes is None:
+        device_bytes = device_memory_bytes()
     resident = word * (m * n + c * m + sb * n + sb * c)
     return resident > device_bytes
 
@@ -725,7 +765,8 @@ def guard_overhead(m: int, n: int, kernel: str, *, b: int = 1, s: int = 1,
 # whose pipelined blocks + scratch cannot be VMEM-resident.
 # --------------------------------------------------------------------------
 
-VMEM_BYTES = 16 * 2 ** 20          # per-core VMEM (TPU v4/v5 class)
+VMEM_BYTES = 16 * 2 ** 20          # Mosaic's default scoped-VMEM limit
+                                   # per kernel on a v5e
 
 
 def pallas_working_set_bytes(block_bytes: int, scratch_bytes: int = 0,

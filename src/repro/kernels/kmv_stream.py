@@ -2,7 +2,7 @@
 
 Computes ``U^T X`` with ``U = K(A, B)`` for an A that does NOT live in
 fast device memory: A arrives pre-chunked as ``Xc: (nc, cr, n)`` row
-blocks resident in HBM/host (``TPUMemorySpace.ANY`` — the pipelined
+blocks resident in HBM/host (``MemorySpace.ANY`` — the pipelined
 BlockSpec machinery never touches it), together with the equally chunked
 right-hand side ``Xvc: (nc, cr, c)``.  The kernel owns TWO VMEM slots
 per stream and overlaps the DMA of chunk ``i+1`` with the contraction of
@@ -142,8 +142,8 @@ def kmv_stream_pallas(Xc: jnp.ndarray, B: jnp.ndarray, Xvc: jnp.ndarray,
         kern,
         grid=(1,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec((r_, n_), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((r_, c_), lambda i: (0, 0)),

@@ -36,7 +36,8 @@ from typing import Optional, Tuple
 import jax
 
 from repro.core.perf_model import (Machine, choose_chunk_rows,
-                                   modeled_fit_cost, slab_fits_hbm)
+                                   device_memory_bytes, modeled_fit_cost,
+                                   slab_fits_hbm)
 
 S_CANDIDATES = (1, 2, 4, 8, 16, 32, 64, 128, 256)
 B_CANDIDATES = (1, 2, 4, 8, 16, 32, 64)
@@ -69,13 +70,14 @@ def _layout_P(layout: str, ndev: int) -> int:
 
 def resolve_options(m: int, n: int, cfg, opts, *, problem: str = "krr",
                     A=None, y=None, mach: Machine = None,
-                    hbm_bytes: int = 16 * 2 ** 30,
+                    hbm_bytes: int = None,
                     layouts=None) -> TunedPlan:
     """Resolve every ``"auto"`` knob of ``opts`` for an (m, n) problem
     (module docstring).  ``A``/``y`` enable the measured-probe
     refinement when ``opts.probe > 0``; without data the Hockney model
     decides alone.  ``layouts`` restricts the layout search space (the
-    fleet solver passes its supported pair)."""
+    fleet solver passes its supported pair).  ``hbm_bytes`` defaults to
+    the device's own memory budget (``perf_model.device_memory_bytes``)."""
     from repro.api import AUTO, LAYOUTS
 
     if not opts.needs_autotune:
@@ -84,6 +86,8 @@ def resolve_options(m: int, n: int, cfg, opts, *, problem: str = "krr",
                                         opts.layout, mach),
                          frontier=())
     ndev = len(jax.devices())
+    if hbm_bytes is None:
+        hbm_bytes = device_memory_bytes()
 
     if opts.method != "sstep":
         s_cands = (1,)

@@ -133,6 +133,21 @@ def test_ladder_descends_to_classical_then_f64():
                                np.asarray(clean.alpha), atol=1e-5)
 
 
+def test_f64_rung_refused_where_it_cannot_compile(monkeypatch):
+    """On a backend without f64 linear solves (a TPU) the ladder stops at
+    the f64 rung with an error naming the device, carrying its events."""
+    import repro.api as api
+    monkeypatch.setattr(api, "F64_UNSUPPORTED_PLATFORMS", ("cpu",))
+    A, _, yr = _data()
+    kr = KernelRidge(lam=0.5, kernel="linear",
+                     options=_opts(b=4, method="classical", guard=True))
+    with inject(FaultPlan(nan_at_iter=40, target="alpha")):
+        with pytest.raises(DivergenceError,
+                           match="f64 rung, which cpu device") as ei:
+            kr.fit(A, yr)
+    assert [e.action for e in ei.value.events] == ["f64"]
+
+
 def test_fallback_disabled_raises():
     A, yc, _ = _data()
     svm = KernelSVM(C=1.0, kernel="rbf",
