@@ -21,9 +21,6 @@ Prints ``name,us_per_call,derived`` CSV rows (see common.emit).
          mid-stream refit correctness                       [DESIGN §13]
   fig10  out-of-core streamed KMV vs resident: modeled overlap
          pipeline + measured parity/ratio gates              [DESIGN §14]
-  fig11  telemetry price + product: enabled-vs-disabled overhead
-         gates (guarded solve, serving drive), audit report,
-         Perfetto trace + Prometheus exposition checks       [DESIGN §15]
   roofline  assigned-arch roofline table from the dry-run   [EXPERIMENTS §Roofline]
 
 ``--fast`` shrinks datasets/iterations (used by CI / test_system).
@@ -44,7 +41,7 @@ def main() -> None:
     from benchmarks import (fig1_dcd_convergence, fig2_bdcd_convergence,
                             fig3_scaling, fig4_breakdown, fig5_slabfree,
                             fig6_predict, fig7_sweep, fig8_resilience,
-                            fig9_serve, fig10_streaming, fig11_obs,
+                            fig9_serve, fig10_streaming,
                             roofline, table4_blocksize)
 
     def paper_dist_subprocess(fast=False):
@@ -82,7 +79,6 @@ def main() -> None:
         "fig8": fig8_resilience.run,
         "fig9": fig9_serve.run,
         "fig10": fig10_streaming.run,
-        "fig11": fig11_obs.run,
         "paper_dist": paper_dist_subprocess,
         "roofline": roofline.run,
     }

@@ -1,6 +1,6 @@
 """Static analysis for the repro tree (DESIGN.md §11).
 
-Three analyzers behind one CLI (``python -m repro.analysis``):
+Four analyzers behind one CLI (``python -m repro.analysis``):
 
 * ``pallas_check`` — Pallas kernel sanitizer (races, coverage holes,
   tile alignment, VMEM budget, unexercised sites)
@@ -10,9 +10,6 @@ Three analyzers behind one CLI (``python -m repro.analysis``):
   collectives vs ``perf_model``'s modeled message schedule)
 * ``guard_check`` — guarded-carry coverage auditor (every floating
   carry leaf must be seen by the divergence-guard health predicate)
-* ``obs_check`` — traced-span pairing auditor (every ``span_begin`` in
-  a function has a same-name ``span_end`` — unmatched begins vanish
-  silently from traces)
 
 Findings carry stable check IDs and honor justified
 ``# repro: noqa[CHK-...]`` suppressions (``findings`` module).
@@ -20,7 +17,7 @@ Findings carry stable check IDs and honor justified
 from .findings import (ERROR, INFO, WARNING, Finding,  # noqa: F401
                        apply_suppressions, render_report)
 
-ANALYZERS = ("pallas", "lint", "comm", "guard", "obs")
+ANALYZERS = ("pallas", "lint", "comm", "guard")
 
 CHECKS = {
     "CHK-RACE": ("pallas", "error",
@@ -45,8 +42,6 @@ CHECKS = {
                   "s-step per-round collectives != classical/s"),
     "CHK-CARRY": ("guard", "error",
                   "guarded-carry leaf missed by the health predicate"),
-    "CHK-SPAN": ("obs", "error",
-                 "traced span_begin without a same-function span_end"),
     "CHK-NOQA": ("-", "error", "suppression without justification"),
 }
 
@@ -54,10 +49,9 @@ CHECKS = {
 def run_all(only=None):
     """Run the selected analyzers (all by default) and resolve
     suppressions; returns the full finding list, suppressed included."""
-    from . import comm_check, guard_check, lint, obs_check, pallas_check
+    from . import comm_check, guard_check, lint, pallas_check
     runners = {"pallas": pallas_check.run, "lint": lint.run,
-               "comm": comm_check.run, "guard": guard_check.run,
-               "obs": obs_check.run}
+               "comm": comm_check.run, "guard": guard_check.run}
     selected = ANALYZERS if not only else tuple(only)
     found = []
     for name in selected:

@@ -182,12 +182,12 @@ class SolverOptions:
                  already O(m*l)-small).
     telemetry:   observability (repro.obs, DESIGN.md §15): a
                  ``repro.obs.Telemetry`` handle — or True for a fresh
-                 one — records host spans around every fit phase plus
-                 traced marks at the round protocol's sync points, and
-                 lands on ``FitResult.telemetry`` for the audit
-                 (``repro.obs.audit_fit``) and the trace exporter.
-                 None (default) or a DISABLED handle compiles the
-                 exact pre-telemetry round fn — zero added ops.
+                 one — records host spans around every fit phase (each
+                 also a profiler annotation ``repro.<span>``) and lands
+                 on ``FitResult.telemetry`` for the trace exporter.
+                 The compiled program is the same with telemetry on,
+                 off (None, the default) or disabled: the round's
+                 phases are named scopes in it either way.
     """
 
     method: str = "sstep"
@@ -356,9 +356,8 @@ class FitResult:
     telemetry: Optional[Telemetry] = None
                                    # the recording handle when the fit
                                    # ran with SolverOptions(telemetry=)
-                                   # — spans/marks/metrics for
-                                   # repro.obs.audit_fit and the trace
-                                   # exporter (DESIGN.md §15)
+                                   # — spans/marks/metrics for the
+                                   # trace exporter (DESIGN.md §15)
 
     def metric_history(self) -> Optional[np.ndarray]:
         """The evaluated convergence trajectory — the canonical accessor
@@ -402,7 +401,7 @@ def _check_positive(value: float, name: str) -> float:
 
 def _active_tel(opts: SolverOptions) -> Optional[Telemetry]:
     """The ENABLED telemetry handle of a fit, or None — a disabled
-    handle maps to None so every traced path compiles mark-free."""
+    handle records nothing, like no handle at all."""
     t = opts.telemetry
     return t if (t is not None and t.enabled) else None
 
@@ -439,10 +438,10 @@ def _resolve_mesh(opts: SolverOptions):
 
 
 @partial(jax.jit, static_argnames=("cfg", "s", "check_every", "slab_free",
-                                   "lowrank", "marks"))
+                                   "lowrank"))
 def _ksvm_serial_tol(A, y, a0, schedule, tol, *, cfg: SVMConfig, s: int,
                      check_every: int, slab_free: bool, op=None,
-                     lowrank: bool = False, marks: bool = False):
+                     lowrank: bool = False):
     gram = None if slab_free else gram_slab
     op = None if gram is not None else op
     if s == 1:
@@ -454,15 +453,12 @@ def _ksvm_serial_tol(A, y, a0, schedule, tol, *, cfg: SVMConfig, s: int,
     # form — the generic oracle would build the m x m gram of Phi
     metric = (ksvm_duality_gap_lowrank if lowrank else ksvm_duality_gap)
     return run_rounds(rf, a0, xs, tol=tol, check_every=check_every,
-                      metric_fn=lambda a: metric(A, y, a, cfg),
-                      marks=marks)
+                      metric_fn=lambda a: metric(A, y, a, cfg))
 
 
-@partial(jax.jit, static_argnames=("cfg", "s", "check_every", "slab_free",
-                                   "marks"))
+@partial(jax.jit, static_argnames=("cfg", "s", "check_every", "slab_free"))
 def _krr_serial_tol(A, y, a0, schedule, tol, *, cfg: KRRConfig, s: int,
-                    check_every: int, slab_free: bool, op=None,
-                    marks: bool = False):
+                    check_every: int, slab_free: bool, op=None):
     gram = None if slab_free else gram_slab
     op = None if gram is not None else op
     if s == 1:
@@ -471,20 +467,17 @@ def _krr_serial_tol(A, y, a0, schedule, tol, *, cfg: KRRConfig, s: int,
         rf = make_sstep_bdcd_round_fn(A, y, cfg, s, gram_fn=gram, op=op)
         xs = pad_rounds(schedule, s)
     return run_rounds(rf, a0, xs, tol=tol, check_every=check_every,
-                      metric_fn=lambda a: krr_rel_residual(A, y, a, cfg),
-                      marks=marks)
+                      metric_fn=lambda a: krr_rel_residual(A, y, a, cfg))
 
 
 @partial(jax.jit, static_argnames=("problem", "cfg", "s", "check_every",
                                    "correct_every", "lowrank",
-                                   "want_metric", "fault_target",
-                                   "marks"))
+                                   "want_metric", "fault_target"))
 def _guarded_serial_chunk(A, y, a0, f0, schedule, tol, fault_round,
                           fault_value, *, problem, cfg, s: int,
                           check_every: int, correct_every: int,
                           lowrank: bool, want_metric: bool,
-                          fault_target: Optional[str] = None, op=None,
-                          marks: bool = False):
+                          fault_target: Optional[str] = None, op=None):
     """One guarded segment (DESIGN.md §12): the guarded round fns over
     the ``(alpha, f)`` carry, driven by the guarded while-loop with
     per-round health checks and periodic residual replacement.  The
@@ -534,7 +527,7 @@ def _guarded_serial_chunk(A, y, a0, f0, schedule, tol, fault_round,
         correct_every=correct_every)
     return run_rounds(rf, (a0, f0), xs, tol=tol, check_every=check_every,
                       metric_fn=metric if want_metric else None,
-                      guard=spec, marks=marks)
+                      guard=spec)
 
 
 # Backends whose compiler cannot build the f64 rung: XLA:TPU implements
@@ -632,8 +625,7 @@ def _run_guarded_serial(problem, A_s, y, a0, schedule, cfg_s,
                 check_every=opts.check_every,
                 correct_every=opts.recompute_every,
                 lowrank=lowrank, want_metric=want_metric,
-                fault_target=fault_target, op=op_cur,
-                marks=tel is not None)
+                fault_target=fault_target, op=op_cur)
             # the segment boundary is already a sync point (the host
             # branches on diverged_round next); syncing INSIDE the span
             # keeps the measured interval honest
@@ -983,15 +975,13 @@ def _solve_cfg(cfg, opts: SolverOptions):
 def _fit(problem: str, A, y, cfg, opts: SolverOptions, *,
          a0=None, rep=None, resume_from=None):
     """Telemetry shell around ``_fit_body``: when the fit carries an
-    enabled handle, activate it (the contextvar target of the traced
-    marks) and bracket the whole call in one phase="fit" span — the
-    window obs/audit.py reconciles against the Hockney model."""
+    enabled handle, bracket the whole call in one phase="fit" span."""
     tel = _active_tel(opts)
     if tel is None:
         return _fit_body(problem, A, y, cfg, opts, a0=a0, rep=rep,
                          resume_from=resume_from)
-    with tel.activate(), tel.span("fit", phase="fit", problem=problem,
-                                  m=int(A.shape[0]), n=int(A.shape[1])):
+    with tel.span("fit", phase="fit", problem=problem,
+                  m=int(A.shape[0]), n=int(A.shape[1])):
         return _fit_body(problem, A, y, cfg, opts, a0=a0, rep=rep,
                          resume_from=resume_from)
 
@@ -1047,14 +1037,19 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
                 jax.block_until_ready(rep[1])
     rep_op, A_s = rep
     cfg_s = _solve_cfg(cfg, opts)
+    # like representation_build, the schedule and train_op spans block
+    # on their outputs (telemetry on only), so each times its own work
+    with _tspan(tel, "schedule", "setup"):
+        schedule = (coordinate_schedule(key, H, m) if problem == "ksvm"
+                    else block_schedule(key, H, m, b))
+        if tel is not None:
+            jax.block_until_ready(schedule)
     if problem == "ksvm":
-        schedule = coordinate_schedule(key, H, m)
         metric_name = "duality_gap"
         gap = (ksvm_duality_gap_lowrank if opts.approx
                else ksvm_duality_gap)
         metric_host = lambda a: float(gap(A_s, y, a, cfg_s))
     else:
-        schedule = block_schedule(key, H, m, b)
         metric_name = "rel_residual"
         # under approx, cfg_s is linear, so the residual's kernel matvec
         # contracts algebraically (kmv_slab_free linear branch:
@@ -1091,18 +1086,19 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
         # serial slab-free paths.  The shard_map bodies rebuild their
         # per-rank operators from their own shards, and the
         # materialized-slab oracle bypasses operators entirely.
-        train_op = None
-        if opts.slab_free:
-            train_op = (rep_op.scale_rows(y) if problem == "ksvm"
-                        else rep_op)
+        train_op = rep_op if opts.slab_free else None
+        if opts.slab_free and problem == "ksvm":
+            with _tspan(tel, "train_op", "setup"):
+                train_op = rep_op.scale_rows(y)
+                if tel is not None:
+                    jax.block_until_ready(train_op)
         if opts.guard:
             (alpha, history, converged, rounds_run, iters_run,
              health) = _run_guarded_serial(
                 problem, A_s, y, a0, schedule, cfg_s, opts, train_op,
                 fingerprint=fp, resume=resume)
         elif not want_metric:
-            # the scan fast path has no sync points — no marks; the
-            # host span still brackets dispatch + completion
+            # the span brackets dispatch + completion
             with _tspan(tel, "solve", "solve", path="fast", s=s):
                 alpha = _serial_fast(problem, A_s, y, a0, schedule,
                                      cfg_s, s, opts.slab_free,
@@ -1118,8 +1114,7 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
             with _tspan(tel, "solve", "solve", path="tol", s=s):
                 res = solve(A_s, y, a0, schedule, tol, cfg=cfg_s, s=s,
                             check_every=opts.check_every,
-                            slab_free=opts.slab_free, op=train_op,
-                            marks=tel is not None, **kw)
+                            slab_free=opts.slab_free, op=train_op, **kw)
                 alpha = res.state
                 # rounds_run is the host sync; inside the span so the
                 # measured interval covers the whole while-loop

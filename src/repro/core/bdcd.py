@@ -28,6 +28,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+
 from .kernels import ExactGramOperator, KernelConfig
 from .loop import run_rounds
 
@@ -64,6 +66,15 @@ def block_schedule(key: jax.Array, H: int, m: int, b: int) -> jnp.ndarray:
     return jax.vmap(one)(keys)
 
 
+@scoped(RECURRENCE)
+def _block_solve(Gblk, uTa, alpha_at, y_at, m, inv_lam):
+    """The exact b x b block solve (paper Alg. 3 lines 6-7): dalpha."""
+    b = Gblk.shape[0]
+    G = inv_lam * Gblk + m * jnp.eye(b, dtype=Gblk.dtype)
+    rhs = y_at - m * alpha_at - inv_lam * uTa
+    return jnp.linalg.solve(G, rhs)
+
+
 def make_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
                        gram_fn: Optional[Callable] = None,
                        op_factory: Optional[Callable] = None,
@@ -98,29 +109,28 @@ def make_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
     if guard:
         def round_fn(carry, idx):             # idx: (b,)
             alpha, f = carry                  # f = K @ alpha, (m,)
-            b = idx.shape[0]
             Gblk = op.cross_block(idx)        # (b, b)
             uTa = f[idx]                      # U^T alpha, free gather
-            G = inv_lam * Gblk + m * jnp.eye(b, dtype=A.dtype)
-            rhs = y[idx] - m * alpha[idx] - inv_lam * uTa
-            dalpha = jnp.linalg.solve(G, rhs)
-            return (alpha.at[idx].add(dalpha),
-                    f + op.apply_at(idx, dalpha))
+            dalpha = _block_solve(Gblk, uTa, alpha[idx], y[idx], m,
+                                  inv_lam)
+            df = op.apply_at(idx, dalpha)
+            with jax.named_scope(SCATTER):
+                return alpha.at[idx].add(dalpha), f + df
 
         return round_fn
 
     def round_fn(alpha, idx):                 # idx: (b,)
-        b = idx.shape[0]
         if gram_fn is not None:               # materialized m x b slab
-            U = gram_fn(A, A[idx], cfg.kernel)
-            Gblk = U[idx, :]
-            uTa = U.T @ alpha
+            with jax.named_scope(KMV):
+                U = gram_fn(A, A[idx], cfg.kernel)
+                uTa = U.T @ alpha
+            with jax.named_scope(CROSS_BLOCK):
+                Gblk = U[idx, :]
         else:                                 # slab-free operator path
             Gblk, uTa = op.round_data(idx, alpha)
-        G = inv_lam * Gblk + m * jnp.eye(b, dtype=A.dtype)
-        rhs = y[idx] - m * alpha[idx] - inv_lam * uTa
-        dalpha = jnp.linalg.solve(G, rhs)
-        return alpha.at[idx].add(dalpha)
+        dalpha = _block_solve(Gblk, uTa, alpha[idx], y[idx], m, inv_lam)
+        with jax.named_scope(SCATTER):
+            return alpha.at[idx].add(dalpha)
 
     return round_fn
 

@@ -30,6 +30,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+
 from .kernels import ExactGramOperator, KernelConfig
 from .loop import run_rounds
 
@@ -75,6 +77,7 @@ def coordinate_schedule(key: jax.Array, H: int, m: int) -> jnp.ndarray:
     return jax.random.randint(key, (H,), 0, m)
 
 
+@scoped(RECURRENCE)
 def _dcd_theta(alpha_i, g, eta, nu):
     """One DCD coordinate update (paper lines 8-16). Returns theta."""
     cand = jnp.clip(alpha_i - g, 0.0, nu) - alpha_i
@@ -131,23 +134,28 @@ def make_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
             eta = op.cross_block(idx)[0, 0] + omega
             g = f[i] - 1.0 + omega * alpha[i]   # u^T alpha = f[i], free
             theta = _dcd_theta(alpha[i], g, eta, nu)
-            return (alpha.at[i].add(theta),
-                    f + op.apply_at(idx, theta[None]))
+            df = op.apply_at(idx, theta[None])
+            with jax.named_scope(SCATTER):
+                return alpha.at[i].add(theta), f + df
 
         return round_fn
 
     def round_fn(alpha, i):
         idx = i[None]
         if gram_fn is not None:                 # materialized m x 1 column
-            u = gram_fn(Atil, Atil[idx], cfg.kernel)[:, 0]
-            eta = u[i] + omega
-            g = u @ alpha - 1.0 + omega * alpha[i]
+            with jax.named_scope(KMV):
+                u = gram_fn(Atil, Atil[idx], cfg.kernel)[:, 0]
+                uTa = u @ alpha
+            with jax.named_scope(CROSS_BLOCK):
+                eta = u[i] + omega
+            g = uTa - 1.0 + omega * alpha[i]
         else:                                   # slab-free operator path
             G, uTa = op.round_data(idx, alpha)  # (1, 1), (1,)
             eta = G[0, 0] + omega
             g = uTa[0] - 1.0 + omega * alpha[i]
         theta = _dcd_theta(alpha[i], g, eta, nu)
-        return alpha.at[i].add(theta)
+        with jax.named_scope(SCATTER):
+            return alpha.at[i].add(theta)
 
     return round_fn
 

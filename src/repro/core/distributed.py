@@ -29,6 +29,10 @@ contraction happen immediately on the psum result, the sampled cross
 block is sliced out of the SAME psum (no extra payload), and for the
 linear kernel the m x sb reduction disappears entirely (only the
 (sb, sb+1) contracted quantities are psummed).
+
+Every collective runs under the ``psum`` named scope, and each
+operator's dot blocks and epilogues under ``kmv`` / ``cross_block``
+(repro.obs, DESIGN.md §15), as the serial operators name theirs.
 """
 from __future__ import annotations
 
@@ -40,12 +44,20 @@ import jax.numpy as jnp
 from repro.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs.scopes import CROSS_BLOCK, KMV, PSUM, SCATTER
+
 from .bdcd import KRRConfig
 from .dcd import SVMConfig
 from .kernels import LINEAR, RBF, KernelConfig, apply_epilogue
 from .loop import pad_rounds, run_rounds
 from .sstep_bdcd import sstep_bdcd_inner, sstep_bdcd_krr
 from .sstep_dcd import sstep_dcd_inner, sstep_dcd_ksvm
+
+
+def _psum(x, axis_name):
+    """``lax.psum`` under the ``psum`` named scope."""
+    with jax.named_scope(PSUM):
+        return jax.lax.psum(x, axis_name)
 
 
 def make_allreduce_gram(axis_name: str, row_sqnorms=None):
@@ -65,17 +77,16 @@ def make_allreduce_gram(axis_name: str, row_sqnorms=None):
     def gram(A_loc, B_loc, cfg: KernelConfig):
         dots_part = A_loc @ B_loc.T                       # (m, sb) partial
         if cfg.name != RBF:
-            return apply_epilogue(jax.lax.psum(dots_part, axis_name), cfg)
+            return apply_epilogue(_psum(dots_part, axis_name), cfg)
         cs_part = jnp.sum(B_loc * B_loc, axis=1)[None, :]  # (1, sb)
         if row_sqnorms is not None:
-            packed = jax.lax.psum(
-                jnp.concatenate([dots_part, cs_part], axis=0), axis_name)
+            packed = _psum(jnp.concatenate([dots_part, cs_part], axis=0),
+                           axis_name)
             return apply_epilogue(packed[:-1], cfg, row_sqnorms,
                                   packed[-1])
-        rs = jax.lax.psum(jnp.sum(A_loc * A_loc, axis=1), axis_name)
-        cs = jax.lax.psum(cs_part[0], axis_name)
-        return apply_epilogue(jax.lax.psum(dots_part, axis_name), cfg,
-                              rs, cs)
+        rs = _psum(jnp.sum(A_loc * A_loc, axis=1), axis_name)
+        cs = _psum(cs_part[0], axis_name)
+        return apply_epilogue(_psum(dots_part, axis_name), cfg, rs, cs)
 
     return gram
 
@@ -123,28 +134,30 @@ class AllreduceGramOperator:
         B_loc = A_loc[idx]
         r = idx.shape[0]
         if cfg.name == LINEAR:
-            cross_part = B_loc @ B_loc.T                  # (r, r) partial
-            mv_part = B_loc @ (A_loc.T @ x)               # (r,)  partial
-            packed = jax.lax.psum(
+            with jax.named_scope(CROSS_BLOCK):
+                cross_part = B_loc @ B_loc.T              # (r, r) partial
+            with jax.named_scope(KMV):
+                mv_part = B_loc @ (A_loc.T @ x)           # (r,)  partial
+            packed = _psum(
                 jnp.concatenate([cross_part, mv_part[:, None]], axis=1), ax)
             return packed[:, :r], packed[:, r]
-        dots = jax.lax.psum(A_loc @ B_loc.T, ax)          # (m, r)
-        cross = dots[idx]                                 # == psummed B B^T
-        if cfg.name == RBF:
-            cs = self.rs[idx]
-            U = apply_epilogue(dots, cfg, self.rs, cs)    # transient
+        with jax.named_scope(KMV):
+            dots_part = A_loc @ B_loc.T                   # (m, r) partial
+        dots = _psum(dots_part, ax)
+        with jax.named_scope(CROSS_BLOCK):
+            cross = dots[idx]                             # == psummed B B^T
+            cs = self.rs[idx] if cfg.name == RBF else None
             G = apply_epilogue(cross, cfg, cs, cs)
-        else:
-            U = apply_epilogue(dots, cfg)
-            G = apply_epilogue(cross, cfg)
-        return G, U.T @ x
+        with jax.named_scope(KMV):
+            U = apply_epilogue(dots, cfg, self.rs, cs)    # transient
+            return G, U.T @ x
 
 
 def _psummed_row_sqnorms(A_loc, cfg: KernelConfig, axis_name: str):
     """Loop-invariant psummed ||a_i||^2 (RBF only; None otherwise)."""
     if cfg.name != RBF:
         return None
-    return jax.lax.psum(jnp.sum(A_loc * A_loc, axis=1), axis_name)
+    return _psum(jnp.sum(A_loc * A_loc, axis=1), axis_name)
 
 
 # --------------------------------------------------------------------------
@@ -280,21 +293,26 @@ class Sharded2dGramOperator:
 
     def round_parts(self, flat):
         A_loc, kernel, m_loc = self.A_loc, self.kernel, self.m_loc
-        onehot = _gather_rows_onehot(flat, self.row0, m_loc, A_loc.dtype)
-        B_loc = jax.lax.psum(onehot @ A_loc, self.data_axis)  # (sb, n_loc)
+        with jax.named_scope(KMV):
+            onehot = _gather_rows_onehot(flat, self.row0, m_loc,
+                                         A_loc.dtype)
+            rows_part = onehot @ A_loc
+        B_loc = _psum(rows_part, self.data_axis)          # (sb, n_loc)
         sb = flat.shape[0]
-        packed = jax.lax.psum(jnp.concatenate(
-            [A_loc @ B_loc.T,                             # (m_loc, sb)
-             B_loc @ B_loc.T], axis=0), self.model_axis)
+        with jax.named_scope(KMV):
+            dots_part = A_loc @ B_loc.T                   # (m_loc, sb)
+        with jax.named_scope(CROSS_BLOCK):
+            cross_part = B_loc @ B_loc.T                  # (sb, sb)
+        packed = _psum(jnp.concatenate([dots_part, cross_part], axis=0),
+                       self.model_axis)
         dots, cross = packed[:m_loc], packed[m_loc:]
         assert cross.shape[0] == sb
-        if kernel.name == RBF:
-            cs = jnp.diagonal(cross)                      # ||b_j||^2 free
-            Q_loc = apply_epilogue(dots, kernel, self.rs_loc, cs)
+        # RBF: ||b_j||^2 is the cross block's diagonal, free
+        cs = jnp.diagonal(cross) if kernel.name == RBF else None
+        with jax.named_scope(CROSS_BLOCK):
             Gblk = apply_epilogue(cross, kernel, cs, cs)
-        else:
-            Q_loc = apply_epilogue(dots, kernel)
-            Gblk = apply_epilogue(cross, kernel)
+        with jax.named_scope(KMV):
+            Q_loc = apply_epilogue(dots, kernel, self.rs_loc, cs)
         return onehot, Q_loc, Gblk
 
 
@@ -353,12 +371,14 @@ def dist_sstep_bdcd_krr_2d(mesh: Mesh, A, y, alpha0, schedule,
             onehot, Q_loc, Gblk = op.round_parts(flat)
             # (3) contract the slab tile IMMEDIATELY (it never leaves this
             #     scope) and fuse every data-axis cross term into ONE psum.
+            with jax.named_scope(KMV):
+                QTalpha_loc = Q_loc.T @ alpha_loc      # (sb,)
             packed = jnp.concatenate([
-                (Q_loc.T @ alpha_loc)[:, None],        # (sb, 1)
+                QTalpha_loc[:, None],                  # (sb, 1)
                 (onehot @ alpha_loc)[:, None],         # (sb, 1)
                 (onehot @ y_loc)[:, None],             # (sb, 1)
             ], axis=1)
-            packed = jax.lax.psum(packed, data_axis)
+            packed = _psum(packed, data_axis)
             QTalpha = packed[:, 0]
             alpha_at = packed[:, 1].reshape(s, b)
             y_at = packed[:, 2].reshape(s, b)
@@ -367,7 +387,8 @@ def dist_sstep_bdcd_krr_2d(mesh: Mesh, A, y, alpha0, schedule,
             dalpha = sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat,
                                       m, inv_lam, s, b, valid)
             # locally-owned scatter-add of the deferred update
-            return alpha_loc + onehot.T @ dalpha.reshape(s * b)
+            with jax.named_scope(SCATTER):
+                return alpha_loc + onehot.T @ dalpha.reshape(s * b)
 
         xs = pad_rounds(sched, s)
         return run_rounds(round_fn, a0_loc, xs).state
@@ -409,15 +430,18 @@ def dist_sstep_dcd_ksvm_2d(mesh: Mesh, A, y, alpha0, schedule,
         def round_fn(alpha_loc, xs):                  # idx: (s,) global
             idx, valid = xs
             onehot, U_loc, G0 = op.round_parts(idx)
-            packed = jax.lax.psum(jnp.concatenate([
-                (U_loc.T @ alpha_loc)[:, None],        # (s, 1)
+            with jax.named_scope(KMV):
+                UTalpha_loc = U_loc.T @ alpha_loc      # (s,)
+            packed = _psum(jnp.concatenate([
+                UTalpha_loc[:, None],                  # (s, 1)
                 (onehot @ alpha_loc)[:, None],         # (s, 1)
             ], axis=1), data_axis)
             u_dot_alpha, alpha_at = packed[:, 0], packed[:, 1]
 
             thetas = sstep_dcd_inner(G0, u_dot_alpha, alpha_at, idx,
                                      nu, omega, s, valid)
-            return alpha_loc + onehot.T @ thetas
+            with jax.named_scope(SCATTER):
+                return alpha_loc + onehot.T @ thetas
 
         xs = pad_rounds(sched, s)
         return run_rounds(round_fn, a0_loc, xs).state

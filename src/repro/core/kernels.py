@@ -19,6 +19,11 @@ operator closed over (or passed) unbatched stays unbatched, so the slab
 GEMM and epilogue of ``matvec``/``round_data`` are computed once per
 round for all F vmapped members — only the contraction against the
 batched right-hand side replicates.
+
+Every concrete operator runs ``matvec`` under the ``kmv`` named scope,
+``cross_block`` under ``cross_block`` and ``apply_at`` under
+``kmv_apply`` (repro.obs, DESIGN.md §15), so each solver's round shows
+its phases in a device trace whichever driver runs it.
 """
 from __future__ import annotations
 
@@ -28,6 +33,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs.scopes import CROSS_BLOCK, KMV, KMV_APPLY, scoped
 
 LINEAR = "linear"
 POLYNOMIAL = "polynomial"
@@ -322,12 +329,14 @@ class ExactGramOperator(GramOperator):
     def rows(self, idx: jnp.ndarray) -> jnp.ndarray:
         return self.A[idx]
 
+    @scoped(KMV)
     def matvec(self, idx: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
         B = self.A[idx]
         if self.matvec_impl is not None:
             return self.matvec_impl(self.A, B, X, self.cfg)
         return kmv_slab_free(self.A, B, X, self.cfg, block=self.block)
 
+    @scoped(CROSS_BLOCK)
     def cross_block(self, idx: jnp.ndarray) -> jnp.ndarray:
         B = self.A[idx]
         return gram_slab(B, B, self.cfg)
@@ -365,6 +374,7 @@ class ExactGramOperator(GramOperator):
             return self.matvec_impl(self.A, Xq, sw, self.cfg)
         return kmv_slab_free(self.A, Xq, sw, self.cfg, block=self.block)
 
+    @scoped(KMV_APPLY)
     def apply_at(self, idx: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
         # always streams through the jnp KMV adjoint: the Pallas
         # matvec_impl accelerates the U^T X reduction only — apply_at's
@@ -400,9 +410,11 @@ class LowRankGramOperator(GramOperator):
     def rows(self, idx: jnp.ndarray) -> jnp.ndarray:
         return self.Phi[idx]
 
+    @scoped(KMV)
     def matvec(self, idx: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
         return self.Phi[idx] @ (self.Phi.T @ X)
 
+    @scoped(CROSS_BLOCK)
     def cross_block(self, idx: jnp.ndarray) -> jnp.ndarray:
         R = self.Phi[idx]
         return R @ R.T
@@ -457,6 +469,7 @@ class LowRankGramOperator(GramOperator):
                 "(SolverOptions(approx='nystrom'))")
         return self.fmap(Xq) @ sw                 # O(l) per query
 
+    @scoped(KMV_APPLY)
     def apply_at(self, idx: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
         return self.Phi @ (self.Phi[idx].T @ w)   # O(m l), no slab
 
@@ -560,9 +573,11 @@ class StreamingGramOperator(GramOperator):
         out = out.astype(X.dtype)
         return out[:, 0] if vec else out
 
+    @scoped(KMV)
     def matvec(self, idx: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
         return self._stream_kmv(self.rows(idx), X)
 
+    @scoped(CROSS_BLOCK)
     def cross_block(self, idx: jnp.ndarray) -> jnp.ndarray:
         B = self.rows(idx)
         return gram_slab(B, B, self.cfg)
@@ -602,6 +617,7 @@ class StreamingGramOperator(GramOperator):
         # rows — one streamed KMV, same pipe as training
         return self._stream_kmv(Xq, sw)
 
+    @scoped(KMV_APPLY)
     def apply_at(self, idx: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
         """``K[:, idx] @ w`` emitted chunk by chunk (the guard path's
         residual recurrence): each chunk builds its (cr, sb) kernel tile

@@ -42,6 +42,10 @@ a fixed-size buffer; (c) metric blow-up detection against the best
 value seen so far.  ``guard=None`` is bit-compatible with the
 pre-guard driver.
 
+The tolerance-check and drift-correction branches run under the
+``metric_check`` and ``drift_correction`` named scopes (repro.obs,
+DESIGN.md §15): op metadata that names them in a device trace.
+
 Everything here is pure ``lax``; the driver runs identically inside
 ``jax.jit`` and inside ``shard_map`` bodies (core/distributed.py).
 """
@@ -51,6 +55,8 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs.scopes import DRIFT_CORRECTION, METRIC_CHECK
 
 NO_TOL = float("-inf")        # sentinel: record the metric, never stop early
 
@@ -169,8 +175,7 @@ def run_rounds(round_fn: Callable, state0: Any, xs: Any, *,
                tol: float = NO_TOL, check_every: int = 1,
                metric_fn: Optional[Callable] = None,
                record_state: bool = False,
-               guard: Optional[GuardSpec] = None,
-               marks: bool = False) -> LoopResult:
+               guard: Optional[GuardSpec] = None) -> LoopResult:
     """Drive ``R = len(xs)`` rounds of ``round_fn`` (see module docstring).
 
     xs is a pytree of arrays with a shared leading round axis.  With
@@ -179,14 +184,6 @@ def run_rounds(round_fn: Callable, state0: Any, xs: Any, *,
     (pass ``tol=NO_TOL`` to record the metric without ever stopping).
     ``guard`` switches to the guarded while-loop driver (module
     docstring; works with or without a metric).
-
-    ``marks`` (static) threads telemetry marks (repro.obs, DESIGN.md
-    §15) into the EXISTING sync points only — the tolerance-check and
-    drift-correction cond branches of the while-loop drivers; the scan
-    fast path has no sync points and is never instrumented.  With
-    ``marks=False`` (the default) the traced code is byte-identical to
-    the pre-telemetry driver: zero added ops, jaxpr-identical
-    (tests/test_obs.py asserts this).
     """
     R = jax.tree_util.tree_leaves(xs)[0].shape[0]
 
@@ -197,8 +194,7 @@ def run_rounds(round_fn: Callable, state0: Any, xs: Any, *,
                              "driver, which stacks no per-round states)")
         return _run_rounds_guarded(round_fn, state0, xs, R, tol=tol,
                                    check_every=check_every,
-                                   metric_fn=metric_fn, guard=guard,
-                                   marks=marks)
+                                   metric_fn=metric_fn, guard=guard)
 
     if metric_fn is None:
         def body(state, x):
@@ -212,8 +208,6 @@ def run_rounds(round_fn: Callable, state0: Any, xs: Any, *,
 
     if check_every < 1:
         raise ValueError(f"check_every must be >= 1, got {check_every}")
-    if marks:
-        from repro.obs.spans import span_begin, span_end
     n_checks = -(-R // check_every)
     mdtype = jax.eval_shape(metric_fn, state0).dtype
     hist0 = jnp.full((n_checks,), jnp.inf, mdtype)
@@ -233,14 +227,8 @@ def run_rounds(round_fn: Callable, state0: Any, xs: Any, *,
 
         def check(args):
             st, h, n = args
-            if marks:                       # static: absent when False
-                span_begin("metric_check")
-            v = metric_fn(st)
-            if marks:
-                # no traced operand on the end mark: shipping the
-                # metric value through the callback roughly doubles
-                # its cost (the value is in hist already)
-                span_end("metric_check")
+            with jax.named_scope(METRIC_CHECK):
+                v = metric_fn(st)
             return h.at[n].set(v), n + 1, v <= tol_v
 
         def skip(args):
@@ -259,8 +247,7 @@ def run_rounds(round_fn: Callable, state0: Any, xs: Any, *,
 def _run_rounds_guarded(round_fn: Callable, state0: Any, xs: Any, R: int,
                         *, tol: float, check_every: int,
                         metric_fn: Optional[Callable],
-                        guard: GuardSpec,
-                        marks: bool = False) -> LoopResult:
+                        guard: GuardSpec) -> LoopResult:
     """The guarded while-loop driver behind ``run_rounds(guard=...)``.
 
     Divergence handling follows the fleet freeze idiom: the unhealthy
@@ -271,8 +258,6 @@ def _run_rounds_guarded(round_fn: Callable, state0: Any, xs: Any, R: int,
     """
     if check_every < 1:
         raise ValueError(f"check_every must be >= 1, got {check_every}")
-    if marks:
-        from repro.obs.spans import span_begin, span_end
     has_metric = metric_fn is not None
     n_checks = -(-R // check_every) if has_metric else 1
     if has_metric:
@@ -314,13 +299,8 @@ def _run_rounds_guarded(round_fn: Callable, state0: Any, xs: Any, R: int,
 
             def correct(args):
                 st, dh, nc = args
-                if marks:                   # static: absent when False
-                    span_begin("drift_correction")
-                st2, drift = guard.correct_fn(st)
-                if marks:
-                    # operand-free (see metric_check): drift lands in
-                    # dhist / SolveHealth.drift, not the mark
-                    span_end("drift_correction")
+                with jax.named_scope(DRIFT_CORRECTION):
+                    st2, drift = guard.correct_fn(st)
                 return st2, dh.at[nc].set(drift), nc + 1
 
             state, dhist, ncorr = jax.lax.cond(
@@ -332,11 +312,8 @@ def _run_rounds_guarded(round_fn: Callable, state0: Any, xs: Any, R: int,
 
             def check(args):
                 st, h, n = args
-                if marks:                   # static: absent when False
-                    span_begin("metric_check")
-                v = metric_fn(st)
-                if marks:
-                    span_end("metric_check")  # operand-free, see above
+                with jax.named_scope(METRIC_CHECK):
+                    v = metric_fn(st)
                 finite = jnp.isfinite(v)
                 blown = jnp.isfinite(best) & (v > blowup * best)
                 return (h.at[n].set(v), n + 1, finite & (v <= tol_v),
@@ -431,7 +408,8 @@ def run_rounds_fleet(round_fn: Callable, state0: Any, xs: Any, *,
 
         def check(args):
             st, h, n, d = args
-            v = metric_fn(st)                        # (F,)
+            with jax.named_scope(METRIC_CHECK):
+                v = metric_fn(st)                    # (F,)
             return h.at[n].set(v), n + 1, d | (v <= tol_v)
 
         def skip(args):

@@ -161,8 +161,8 @@ def modeled_fit_cost(m: int, n: int, kernel: str, *, b: int = 1,
         prob = Problem(m=m, n=n, b=max(b, 1), H=H, kernel=kernel)
     cost = (bdcd_cost(prob, mach, P) if s <= 1
             else sstep_bdcd_cost(prob, mach, P, s))
-    # problem identity rides along so downstream consumers (the
-    # repro.obs audit re-pricing guard overhead) need only this dict
+    # problem identity rides along so a consumer of FitResult.comm
+    # needs only this dict
     cost = dict(cost, m=m, n=n, kernel=kernel, b=b,
                 P=P, s=s, iters=iters, approx=approx,
                 landmarks=landmarks if approx else 0)

@@ -38,11 +38,14 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+
 from .bdcd import KRRConfig
 from .kernels import ExactGramOperator
 from .loop import pad_rounds, run_rounds
 
 
+@scoped(RECURRENCE)
 def sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat, m, inv_lam,
                      s, b, valid=None):
     """The redundant local phase shared by the serial and 2D-distributed
@@ -126,7 +129,9 @@ def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
             d = dalpha.reshape(s * b)
             # duplicate coordinates in ``flat`` accumulate identically
             # in .at[].add and in the K[:, flat] @ d contraction
-            return (alpha.at[flat].add(d), f + op.apply_at(flat, d))
+            df = op.apply_at(flat, d)
+            with jax.named_scope(SCATTER):
+                return alpha.at[flat].add(d), f + df
 
         return round_fn
 
@@ -136,9 +141,11 @@ def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
         flat = idx.reshape(s * b)
         # --- communication phase ----------------------------------------
         if gram_fn is not None:                # materialized m x sb slab
-            Q = gram_fn(A, A[flat], cfg.kernel)
-            Gblk = Q[flat, :]                  # (s*b, s*b)
-            QTalpha = Q.T @ alpha              # (s*b,)
+            with jax.named_scope(KMV):
+                Q = gram_fn(A, A[flat], cfg.kernel)
+                QTalpha = Q.T @ alpha          # (s*b,)
+            with jax.named_scope(CROSS_BLOCK):
+                Gblk = Q[flat, :]              # (s*b, s*b)
         else:                                  # slab-free operator path
             Gblk, QTalpha = op.round_data(flat, alpha)
         y_at = y[idx]                          # (s, b)
@@ -147,7 +154,8 @@ def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
         # --- redundant local phase: s block solves -----------------------
         dalpha = sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat,
                                   m, inv_lam, s, b, valid)
-        return alpha.at[flat].add(dalpha.reshape(s * b))
+        with jax.named_scope(SCATTER):
+            return alpha.at[flat].add(dalpha.reshape(s * b))
 
     return round_fn
 

@@ -36,11 +36,14 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+
 from .dcd import SVMConfig
 from .kernels import ExactGramOperator
 from .loop import pad_rounds, run_rounds
 
 
+@scoped(RECURRENCE)
 def sstep_dcd_inner(G0, u_dot_alpha, alpha_at, idx_s, nu, omega, s,
                     valid=None):
     """The redundant local phase shared by the serial and 2D-distributed
@@ -122,8 +125,9 @@ def make_sstep_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
             u_dot_alpha = f[idx_s]               # U^T alpha, free gather
             thetas = sstep_dcd_inner(G0, u_dot_alpha, alpha[idx_s],
                                      idx_s, nu, omega, s, valid)
-            return (alpha.at[idx_s].add(thetas),
-                    f + op.apply_at(idx_s, thetas))
+            df = op.apply_at(idx_s, thetas)      # K[:, idx_s] @ thetas
+            with jax.named_scope(SCATTER):
+                return alpha.at[idx_s].add(thetas), f + df
 
         return round_fn
 
@@ -131,16 +135,19 @@ def make_sstep_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
         idx_s, valid = xs
         # --- communication phase: one fused round, one (would-be) psum ---
         if gram_fn is not None:                  # materialized m x s slab
-            U = gram_fn(Atil, Atil[idx_s], cfg.kernel)
-            G0 = U[idx_s, :]                     # V_k^T U_k, (s, s)
-            u_dot_alpha = U.T @ alpha            # (s,)
+            with jax.named_scope(KMV):
+                U = gram_fn(Atil, Atil[idx_s], cfg.kernel)
+                u_dot_alpha = U.T @ alpha        # (s,)
+            with jax.named_scope(CROSS_BLOCK):
+                G0 = U[idx_s, :]                 # V_k^T U_k, (s, s)
         else:                                    # slab-free operator path
             G0, u_dot_alpha = op.round_data(idx_s, alpha)
 
         # --- redundant local phase: s sequential scalar solves ----------
         thetas = sstep_dcd_inner(G0, u_dot_alpha, alpha[idx_s], idx_s,
                                  nu, omega, s, valid)
-        return alpha.at[idx_s].add(thetas)       # alpha_{sk+s}
+        with jax.named_scope(SCATTER):
+            return alpha.at[idx_s].add(thetas)   # alpha_{sk+s}
 
     return round_fn
 

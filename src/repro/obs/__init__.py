@@ -1,33 +1,29 @@
 """repro.obs — unified telemetry (DESIGN.md §15).
 
-* ``spans``   — the ``Telemetry`` handle: host spans + traced marks at
-  the round protocol's existing sync points.
+* ``scopes``  — the names of the round's phases, carried by the
+  compiled program as ``jax.named_scope`` metadata (``SCOPES``).
+* ``spans``   — the ``Telemetry`` handle: host spans, each also a
+  profiler ``TraceAnnotation`` named ``repro.<span>``.
 * ``metrics`` — counters/gauges/histograms with Prometheus/JSON export.
-* ``audit``   — modeled-vs-measured per-phase reconciliation of a fit
-  against ``perf_model.modeled_fit_cost``.
 * ``export``  — Chrome-trace/Perfetto JSON of any recorded window.
 
-CLI: ``python -m repro.obs {report,trace,scrape}``.
+CLI: ``python -m repro.obs {trace,scrape}``.
 
-Import note: ``core/loop.py`` imports ``obs.spans`` from inside the
-round drivers, so this package __init__ stays dependency-light — the
-audit (which imports ``repro.core.perf_model``) loads lazily via
-module ``__getattr__`` to keep ``repro.core`` -> ``repro.obs`` acyclic.
+Import note: ``repro.core`` imports ``obs.scopes``, so this package
+stays dependency-light (jax and the standard library only).
 """
 from .metrics import (Counter, Gauge, Histogram,  # noqa: F401
                       MetricsRegistry, default_registry)
-from .spans import (Mark, Span, Telemetry, active_telemetry,  # noqa: F401
-                    chunk_mark, span_begin, span_end)
+from .scopes import SCOPES  # noqa: F401
+from .spans import Mark, Span, Telemetry  # noqa: F401
 
 _LAZY = {
-    "audit_fit": "audit", "AuditReport": "audit", "PhaseRow": "audit",
     "to_chrome_trace": "export", "validate_chrome_trace": "export",
     "save_trace": "export", "load_trace": "export",
 }
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "default_registry", "Mark", "Span", "Telemetry",
-           "active_telemetry", "chunk_mark", "span_begin", "span_end",
+           "default_registry", "Mark", "SCOPES", "Span", "Telemetry",
            *_LAZY]
 
 

@@ -1,7 +1,6 @@
-"""``python -m repro.obs`` — record, audit, and export telemetry from
+"""``python -m repro.obs`` — record and export telemetry from
 self-contained demo workloads (mirrors the ``repro.analysis`` CLI).
 
-    python -m repro.obs report            # instrumented solve -> audit table
     python -m repro.obs trace --out t.json  # solve + serving window -> trace
     python -m repro.obs scrape            # serving drive -> Prometheus text
 
@@ -72,19 +71,15 @@ def _demo_serve(m: int, iters: int, tickets: int):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs",
-        description="telemetry demos: audit report, Perfetto trace, "
-                    "Prometheus scrape")
+        description="telemetry demos: Perfetto trace, Prometheus scrape")
     # shared demo knobs live on a parent so they parse AFTER the
-    # subcommand too (python -m repro.obs report --m 256)
+    # subcommand too (python -m repro.obs trace --m 256)
     shared = argparse.ArgumentParser(add_help=False)
     shared.add_argument("--m", type=int, default=192,
                         help="demo problem rows")
     shared.add_argument("--iters", type=int, default=256,
                         help="demo solve iteration budget")
     sub = ap.add_subparsers(dest="cmd", required=True)
-    sub.add_parser("report", parents=[shared],
-                   help="instrumented demo solve -> "
-                        "modeled-vs-measured audit table")
     p_trace = sub.add_parser("trace", parents=[shared],
                              help="record a solve + serving window, "
                                   "export Chrome trace")
@@ -96,16 +91,9 @@ def main(argv=None) -> int:
     p_scrape.add_argument("--tickets", type=int, default=64)
     args = ap.parse_args(argv)
 
-    if args.cmd == "report":
-        from repro.obs.audit import audit_fit
-        result, _tel = _demo_fit(args.m, args.iters)
-        report = audit_fit(result)
-        print(report.render())
-        return 0
-
     if args.cmd == "trace":
         from repro.obs.export import save_trace
-        result, tel = _demo_fit(args.m, args.iters)
+        _result, tel = _demo_fit(args.m, args.iters)
         engine, stel = _demo_serve(args.m, args.iters, tickets=32)
         # both windows ride one trace: merge the serving log into the
         # solve handle (timestamps share the perf_counter clock)

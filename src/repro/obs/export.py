@@ -3,13 +3,14 @@
 ``to_chrome_trace`` turns a ``Telemetry`` handle's span/mark log into
 the Chrome Trace Event JSON format (the ``traceEvents`` array of
 "X"/"B"/"E"/"i" events, microsecond timestamps) that chrome://tracing
-and https://ui.perfetto.dev open directly.  Host spans land on the
-"host" track, traced marks on the "traced" track; per-event args carry
-the span's free-form payload.
+and https://ui.perfetto.dev open directly.  Host spans ("X") and marks
+("i") land on the "host" track; per-event args carry the span's
+free-form payload.  (The device's side of a fit is in a profile taken
+with ``jax.profiler.trace``, where each span is also an annotation.)
 
-``validate_chrome_trace`` is the schema check the tests and the fig11
-benchmark gate on: required keys per event, non-negative ts/dur,
-balanced per-track B/E nesting.
+``validate_chrome_trace`` is the schema check the tests gate on:
+required keys per event, non-negative ts/dur, balanced per-track B/E
+nesting.
 """
 from __future__ import annotations
 
@@ -17,7 +18,6 @@ import json
 from typing import List, Optional
 
 HOST_TID = 1
-TRACED_TID = 2
 PID = 1
 
 _PHASES = {"X", "B", "E", "i", "M"}
@@ -38,30 +38,17 @@ def to_chrome_trace(telemetry, *, process_name: str = "repro") -> dict:
          "args": {"name": process_name}},
         {"name": "thread_name", "ph": "M", "pid": PID, "tid": HOST_TID,
          "args": {"name": "host"}},
-        {"name": "thread_name", "ph": "M", "pid": PID, "tid": TRACED_TID,
-         "args": {"name": "traced"}},
     ]
     for s in telemetry.spans:
         events.append({"name": s.name, "cat": s.phase, "ph": "X",
                        "ts": us(s.t0), "dur": max(us(s.t1) - us(s.t0), 0.0),
                        "pid": PID, "tid": HOST_TID,
                        "args": {str(k): v for k, v in s.args.items()}})
-    # traced begin/end marks export as paired complete ("X") events:
-    # unordered-callback arrival can interleave B/E of different names,
-    # which strict B/E stack nesting would reject — pairing first keeps
-    # the trace valid while preserving the measured intervals
-    for s in telemetry.paired_marks():
-        events.append({"name": s.name, "cat": s.phase, "ph": "X",
-                       "ts": us(s.t0), "dur": max(us(s.t1) - us(s.t0), 0.0),
-                       "pid": PID, "tid": TRACED_TID,
-                       "args": {str(k): v for k, v in s.args.items()}})
     for m in telemetry.marks:
-        if m.kind != "i":
-            continue
         args = {} if m.value is None else {"value": m.value}
         events.append({"name": m.name, "cat": m.phase, "ph": "i",
                        "ts": us(m.t), "s": "t", "pid": PID,
-                       "tid": TRACED_TID, "args": args})
+                       "tid": HOST_TID, "args": args})
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 

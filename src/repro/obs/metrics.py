@@ -42,8 +42,7 @@ class _Bound:
     """A counter/gauge pre-resolved to one label set.  ``labels()``
     builds the key ONCE; hot paths (per-ticket engine counters) then
     pay a single dict add per ``inc`` instead of rebuilding the sorted
-    label tuple on every call (~4x cheaper — the fig11 gate prices
-    this)."""
+    label tuple on every call (~4x cheaper)."""
 
     __slots__ = ("_inst", "_key", "_floor")
 

@@ -1,59 +1,32 @@
-"""Span/trace recorder for solves and serving windows (DESIGN.md §15).
+"""Span recorder for solves and serving windows (DESIGN.md §15).
 
-Two kinds of observation, one ``Telemetry`` handle:
+One ``Telemetry`` handle records two kinds of host observation:
 
-  * HOST SPANS — ``with tel.span("representation_build", "setup"):`` —
-    monotonic-clock intervals around host-side phases (representation
-    build, guarded segments, engine steps).  Zero traced footprint.
-  * TRACED MARKS — ``span_begin``/``span_end``/``chunk_mark`` — emitted
-    INSIDE jitted code via ``jax.debug.callback``, but ONLY at existing
-    sync points of the round protocol: the tolerance-check branch and
-    the guarded drift-correction branch of ``core/loop.py``'s
-    while-loop drivers, and the s-step chunk seams of the chunked
-    executors.  The scan fast path has no sync points and carries no
-    marks; when marks are off (the static ``marks=False`` flag) the
-    traced code is BYTE-IDENTICAL to the uninstrumented driver — zero
-    added ops, asserted jaxpr-identical in tests/test_obs.py.
+  * SPANS — ``with tel.span("representation_build", "setup"):`` —
+    intervals on ``time.perf_counter`` around host-side phases (the
+    fit, its schedule draw and representation build, the solve,
+    guarded segments, engine steps).  Each span also opens a
+    ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so a
+    profile taken around the work shows the program's phases on the
+    profiler's own clock, beside the device's operations.
+  * MARKS — ``tel.mark("fallback", "guard")`` — instant host events.
 
-Why a module-level active slot instead of closing over the handle: a
-``Telemetry`` captured inside a jitted function would either be a
-static arg (retrace per handle — the CHK-STATIC hazard) or baked into
-the trace (first handle wins forever through the jit cache).  Instead
-the callbacks are MODULE-LEVEL functions that look up the ACTIVE
-telemetry at call time (``tel.activate()`` around the jitted call sets
-it), so one compiled executable serves every handle — and runs
-silently when none is active.  The slot is a plain module global, NOT
-a contextvar: ``jax.debug.callback`` executes on runtime threads,
-where a contextvar set on the solver thread would be invisible.
+Inside jitted code the handle records nothing: the round's phases are
+named in the compiled program by ``jax.named_scope`` (``obs.scopes``),
+which is op metadata, so a fit compiles the same program with telemetry
+on or off.
 
-Timing caveat: ``jax.debug.callback`` is unordered (the ordered
-``io_callback`` is not allowed inside ``lax.cond``/``while_loop``
-branches), so mark timestamps are host arrival times near — not
-exactly at — the device-side event.  Spans paired from begin/end marks
-are therefore approximate; the audit (obs/audit.py) treats them as
-shares of wall time, never as absolute truth.
+A span that brackets device work must end with a ``block_until_ready``
+on the phase's output, or it times the dispatch rather than the work.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import time
-from functools import partial
 from typing import Any, Dict, List, Optional
 
 import jax
-
-# The process's active recording handle (None = record nothing).  A
-# plain global on purpose: debug callbacks fire on runtime threads, so
-# thread/context-local storage set by the solver thread would not be
-# visible to them.  Solves are driven one at a time per process
-# (facade + executors are host-serial), so a single slot suffices.
-_ACTIVE: Optional["Telemetry"] = None
-
-
-def active_telemetry() -> Optional["Telemetry"]:
-    """The ``Telemetry`` the process currently records into, or None."""
-    return _ACTIVE
 
 
 @dataclasses.dataclass
@@ -75,13 +48,11 @@ class Span:
 
 @dataclasses.dataclass
 class Mark:
-    """One instantaneous event.  ``kind`` follows the Chrome-trace
-    phase letters: "B" (span begin), "E" (span end), "i" (instant)."""
+    """One instantaneous host event."""
 
     name: str
     phase: str
     t: float
-    kind: str = "i"
     value: Optional[float] = None
 
 
@@ -91,9 +62,7 @@ class Telemetry:
 
     Holds the span/mark log plus a ``MetricsRegistry``
     (counters/gauges/histograms — obs/metrics.py).  ``enabled=False``
-    makes every recording call a no-op AND keeps the traced fast paths
-    uninstrumented (the facade maps a disabled handle to
-    ``marks=False``, the same compiled code as no telemetry at all).
+    makes every recording call a no-op, and opens no annotation.
     """
 
     def __init__(self, *, enabled: bool = True, metrics=None):
@@ -103,47 +72,29 @@ class Telemetry:
         self.marks: List[Mark] = []
         self.metrics = MetricsRegistry() if metrics is None else metrics
 
-    # -- host-side recording -------------------------------------------
-
     @contextlib.contextmanager
     def span(self, name: str, phase: str = "host", **args):
-        """Record a closed host span around the with-body (no-op when
-        disabled).  The span is appended at EXIT, so the log stays
-        ordered by end time."""
+        """Record a closed host span around the with-body, inside a
+        profiler annotation ``repro.<name>`` (no-op when disabled).
+        The span is appended at EXIT, so the log stays ordered by end
+        time."""
         if not self.enabled:
             yield None
             return
-        t0 = time.perf_counter()
-        try:
-            yield None
-        finally:
-            self.spans.append(Span(name, phase, t0, time.perf_counter(),
-                                   dict(args)))
+        with jax.profiler.TraceAnnotation(f"repro.{name}"):
+            t0 = time.perf_counter()
+            try:
+                yield None
+            finally:
+                self.spans.append(Span(name, phase, t0,
+                                       time.perf_counter(), dict(args)))
 
-    def mark(self, name: str, phase: str = "host", value=None,
-             kind: str = "i") -> None:
+    def mark(self, name: str, phase: str = "host", value=None) -> None:
         """Record one instant event (no-op when disabled)."""
         if not self.enabled:
             return
-        self.marks.append(Mark(name, phase, time.perf_counter(), kind,
+        self.marks.append(Mark(name, phase, time.perf_counter(),
                                None if value is None else float(value)))
-
-    @contextlib.contextmanager
-    def activate(self):
-        """Make this handle the process's active recorder — the target
-        of the traced ``span_begin``/``span_end``/``chunk_mark``
-        callbacks fired under the with-body.  Disabled handles activate
-        as None (callbacks stay silent); the prior handle is restored
-        on exit, so activations nest."""
-        global _ACTIVE
-        prev = _ACTIVE
-        _ACTIVE = self if self.enabled else None
-        try:
-            yield self
-        finally:
-            _ACTIVE = prev
-
-    # -- derived views --------------------------------------------------
 
     def window(self):
         """(t_min, t_max) over everything recorded, or None when empty."""
@@ -153,65 +104,8 @@ class Telemetry:
             return None
         return min(ts), max(te)
 
-    def paired_marks(self) -> List[Span]:
-        """Stitch "B"/"E" marks into approximate spans (see module
-        docstring for the timing caveat).  Pairing is per-name LIFO in
-        record order; unmatched begins are dropped — the CHK-SPAN static
-        check (repro.analysis) keeps call sites paired at the source."""
-        open_by_name: Dict[str, List[Mark]] = {}
-        out: List[Span] = []
-        for m in self.marks:
-            if m.kind == "B":
-                open_by_name.setdefault(m.name, []).append(m)
-            elif m.kind == "E" and open_by_name.get(m.name):
-                b = open_by_name[m.name].pop()
-                args = {} if m.value is None else {"value": m.value}
-                out.append(Span(m.name, m.phase, b.t, m.t, args))
-        return out
-
     def clear(self) -> None:
         """Drop every recorded span/mark (metrics are kept — counters
         are cumulative by design)."""
         self.spans.clear()
         self.marks.clear()
-
-
-# ---------------------------------------------------------------------------
-# Traced-side marks.  These are called at TRACE time inside jitted code;
-# the partials they stage are module-level functions, so the jit cache
-# is stable across Telemetry handles (the handle is resolved at RUN time
-# through the contextvar).  Callers gate every call site on a static
-# ``marks`` bool — the disabled trace contains no callback at all.
-# ---------------------------------------------------------------------------
-
-def _record_mark(name: str, phase: str, kind: str, value=None) -> None:
-    tel = _ACTIVE
-    if tel is None:
-        return
-    tel.marks.append(Mark(name, phase, time.perf_counter(), kind,
-                          None if value is None else float(value)))
-
-
-def span_begin(name: str, phase: str = "round") -> None:
-    """Open a traced span: emits a "B" mark through an unordered debug
-    callback.  MUST be paired with a ``span_end`` of the same name
-    inside the same function, at an existing sync point — enforced
-    statically by repro.analysis CHK-SPAN."""
-    jax.debug.callback(partial(_record_mark, name, phase, "B"))
-
-
-def span_end(name: str, value=None, phase: str = "round") -> None:
-    """Close the traced span opened by ``span_begin(name)``; ``value``
-    (a traced scalar) rides along into the mark."""
-    if value is None:
-        jax.debug.callback(partial(_record_mark, name, phase, "E"))
-    else:
-        jax.debug.callback(partial(_record_mark, name, phase, "E"), value)
-
-
-def chunk_mark(name: str, value=None, phase: str = "round") -> None:
-    """One traced instant ("i") mark — chunk boundaries, round seams."""
-    if value is None:
-        jax.debug.callback(partial(_record_mark, name, phase, "i"))
-    else:
-        jax.debug.callback(partial(_record_mark, name, phase, "i"), value)

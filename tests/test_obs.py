@@ -1,8 +1,15 @@
 """repro.obs tests: metrics registry semantics, span/mark recording,
-zero-cost-when-disabled (jaxpr identity), end-to-end instrumented fits,
-the modeled-vs-measured audit, Chrome-trace export, serving metrics,
+the round's named scopes in every driver's compiled program, one
+program with telemetry on or off, spans as profiler annotations,
+end-to-end instrumented fits, Chrome-trace export, serving metrics,
 and the CLI (DESIGN.md §15)."""
+import glob
 import json
+import os
+import pathlib
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -10,11 +17,13 @@ import numpy as np
 import pytest
 
 from repro.api import KernelRidge, SolverOptions
-from repro.obs import (MetricsRegistry, Telemetry, active_telemetry,
-                       default_registry)
-from repro.obs.audit import audit_fit
+from repro.obs import MetricsRegistry, SCOPES, Telemetry, default_registry
+from repro.obs import scopes
 from repro.obs.export import (load_trace, save_trace, to_chrome_trace,
                               validate_chrome_trace)
+
+
+SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
 
 
 def _problem(m=48, n=4, seed=0):
@@ -111,7 +120,7 @@ class TestMetrics:
 
 
 # ---------------------------------------------------------------------------
-# Telemetry spans, marks, activation
+# Telemetry spans and marks
 # ---------------------------------------------------------------------------
 
 class TestTelemetry:
@@ -135,91 +144,253 @@ class TestTelemetry:
         with tel.span("x"):
             tel.mark("y")
         assert tel.spans == [] and tel.marks == []
-        with tel.activate():
-            # disabled handles activate as None: callbacks stay silent
-            assert active_telemetry() is None
-
-    def test_activation_nests_and_restores(self):
-        a, b = Telemetry(), Telemetry()
-        assert active_telemetry() is None
-        with a.activate():
-            assert active_telemetry() is a
-            with b.activate():
-                assert active_telemetry() is b
-            assert active_telemetry() is a
-        assert active_telemetry() is None
-
-    def test_paired_marks_lifo_and_unmatched_dropped(self):
-        from repro.obs.spans import Mark
-        tel = Telemetry()
-        tel.marks = [Mark("a", "round", 1.0, "B"),
-                     Mark("a", "round", 2.0, "B"),
-                     Mark("a", "round", 3.0, "E", value=7.0),
-                     Mark("b", "round", 4.0, "B"),   # never closed
-                     Mark("a", "round", 5.0, "E")]
-        pairs = tel.paired_marks()
-        assert [(p.t0, p.t1) for p in pairs] == [(2.0, 3.0), (1.0, 5.0)]
-        assert pairs[0].args == {"value": 7.0}
-        assert all(p.name == "a" for p in pairs)
-
-    def test_traced_marks_recorded_under_jit(self):
-        from repro.obs.spans import chunk_mark, span_begin, span_end
-
-        @jax.jit
-        def f(x):
-            span_begin("seg")
-            y = x * 2.0
-            chunk_mark("seam", value=jnp.sum(y))
-            span_end("seg")
-            return y
-
-        tel = Telemetry()
-        with tel.activate():
-            jax.block_until_ready(f(jnp.ones(4)))
-        kinds = sorted(m.kind for m in tel.marks)
-        assert kinds == ["B", "E", "i"]
-        seam = [m for m in tel.marks if m.name == "seam"][0]
-        assert seam.value == 8.0
-        assert len(tel.paired_marks()) == 1
-
-    def test_no_active_handle_is_silent(self):
-        from repro.obs.spans import chunk_mark
-
-        @jax.jit
-        def f(x):
-            chunk_mark("quiet")
-            return x + 1
-
-        jax.block_until_ready(f(jnp.zeros(2)))   # must not raise
+        assert tel.window() is None
 
 
 # ---------------------------------------------------------------------------
-# zero ops when disabled (the acceptance bar: jaxpr-identical)
+# named scopes: the round's phases in the compiled program
 # ---------------------------------------------------------------------------
 
-class TestZeroCostDisabled:
-    def _jaxpr(self, marks):
-        from repro.api import _krr_serial_tol
-        from repro.core.bdcd import KRRConfig
-        from repro.core.kernels import KernelConfig
-        cfg = KRRConfig(lam=1.0, kernel=KernelConfig("rbf", sigma=1.0))
-        A = jnp.ones((16, 3))
-        y = jnp.ones(16)
-        a0 = jnp.zeros(16)
-        sched = jnp.zeros((8, 4), jnp.int32)
-        return str(jax.make_jaxpr(
-            lambda A, y, a0, sched: _krr_serial_tol(
-                A, y, a0, sched, 1e-6, cfg=cfg, s=4, check_every=2,
-                slab_free=False, marks=marks))(A, y, a0, sched))
+def _op_owners(text: str) -> set:
+    """The scopes that own some op of a compiled program's text."""
+    return {scopes.owner(p) for p in re.findall(r'op_name="([^"]*)"', text)
+            } - {None}
 
-    def test_marks_off_has_no_callback_and_is_deterministic(self):
-        off1, off2 = self._jaxpr(False), self._jaxpr(False)
-        assert off1 == off2
-        assert "callback" not in off1
 
-    def test_marks_on_adds_only_callbacks(self):
-        on = self._jaxpr(True)
-        assert "callback" in on
+def _small():
+    A, y = _problem(m=32)
+    return A, jnp.sign(y) + (y == 0), jnp.zeros(32)
+
+
+def _sstep_dcd():
+    from repro.core import SVMConfig
+    from repro.core.sstep_dcd import sstep_dcd_ksvm
+    A, y, a0 = _small()
+    return sstep_dcd_ksvm.lower(A, y, a0, jnp.zeros(16, jnp.int32),
+                                SVMConfig(), 4)
+
+
+def _sstep_bdcd():
+    from repro.core import KRRConfig
+    from repro.core.sstep_bdcd import sstep_bdcd_krr
+    A, y, a0 = _small()
+    return sstep_bdcd_krr.lower(A, y, a0, jnp.zeros((8, 2), jnp.int32),
+                                KRRConfig(), 4)
+
+
+def _dcd():
+    from repro.core import SVMConfig
+    from repro.core.dcd import dcd_ksvm
+    A, y, a0 = _small()
+    return dcd_ksvm.lower(A, y, a0, jnp.zeros(8, jnp.int32), SVMConfig())
+
+
+def _bdcd():
+    from repro.core import KRRConfig
+    from repro.core.bdcd import bdcd_krr
+    A, y, a0 = _small()
+    return bdcd_krr.lower(A, y, a0, jnp.zeros((8, 2), jnp.int32),
+                          KRRConfig())
+
+
+def _guarded_args():
+    from repro.core import KRRConfig
+    from repro.core.kernels import ExactGramOperator, KernelConfig
+    A, y, a0 = _small()
+    cfg = KRRConfig(lam=1.0, kernel=KernelConfig("rbf"))
+    return (A, y, a0, jnp.zeros(32), jnp.zeros((8, 2), jnp.int32),
+            jnp.asarray(1e-6), -1, float("nan")), dict(
+        problem="krr", cfg=cfg, s=4, check_every=2, correct_every=1,
+        lowrank=False, want_metric=True,
+        op=ExactGramOperator(A, cfg.kernel))
+
+
+def _guarded():
+    from repro.api import _guarded_serial_chunk
+    args, kw = _guarded_args()
+    return _guarded_serial_chunk.lower(*args, **kw)
+
+
+def _tol_args():
+    from repro.core import KRRConfig
+    from repro.core.kernels import ExactGramOperator, KernelConfig
+    A, y, a0 = _small()
+    cfg = KRRConfig(lam=1.0, kernel=KernelConfig("rbf"))
+    return (A, y, a0, jnp.zeros((8, 2), jnp.int32), jnp.asarray(1e-6)), \
+        dict(cfg=cfg, s=4, check_every=2, slab_free=True,
+             op=ExactGramOperator(A, cfg.kernel))
+
+
+def _tol():
+    from repro.api import _krr_serial_tol
+    args, kw = _tol_args()
+    return _krr_serial_tol.lower(*args, **kw)
+
+
+def _fleet():
+    from repro.core import KRRConfig
+    from repro.core.kernels import ExactGramOperator
+    from repro.tune.fleet import _fleet_serial
+    A, y, _ = _small()
+    cfg = KRRConfig()
+    return _fleet_serial.lower(
+        A, y, jnp.zeros((3, 32)), jnp.asarray([0.5, 1.0, 2.0]),
+        jnp.zeros((8, 2), jnp.int32), jnp.asarray(1e-6),
+        ExactGramOperator(A, cfg.kernel), problem="krr", cfg=cfg, s=4,
+        check_every=2, want_metric=True)
+
+
+ROUND = {"kmv", "cross_block", "recurrence", "scatter"}
+SERIAL_DRIVERS = {
+    "sstep_dcd": (_sstep_dcd, ROUND),
+    "sstep_bdcd": (_sstep_bdcd, ROUND),
+    "dcd": (_dcd, ROUND),
+    "bdcd": (_bdcd, ROUND),
+    # the guarded round reads U^T alpha from its carried residual and
+    # advances it by apply_at; the drift correction recomputes it
+    "guarded": (_guarded, {"kmv_apply", "cross_block", "recurrence",
+                           "scatter", "metric_check", "drift_correction"}),
+    "tol": (_tol, ROUND | {"metric_check"}),
+    "fleet": (_fleet, ROUND | {"metric_check"}),
+}
+LAYOUT_DRIVERS = {"1d": ROUND | {"psum"}, "2d": ROUND | {"psum"}}
+
+_DIST_SCRIPT = r"""
+import json, re
+import jax, jax.numpy as jnp
+from repro.api import _dist_call
+from repro.core import KRRConfig, SVMConfig
+from repro.compat import make_mesh_auto
+out = {}
+A = jnp.ones((32, 8)) / 8
+y = jnp.where(jnp.arange(32) % 2 == 0, 1.0, -1.0)
+for layout, shape in (("1d", (1, 4)), ("2d", (2, 2))):
+    mesh = make_mesh_auto(shape, ("data", "model"))
+    for problem, cfg, sched in (
+            ("ksvm", SVMConfig(), jnp.zeros(16, jnp.int32)),
+            ("krr", KRRConfig(), jnp.zeros((8, 2), jnp.int32))):
+        f = jax.jit(lambda A, y, a0, sc: _dist_call(
+            problem, layout, mesh, A, y, a0, sc, cfg, 4, True))
+        text = f.lower(A, y, jnp.zeros(32), sched).compile().as_text()
+        out[f"{layout}.{problem}"] = sorted(set(
+            re.findall(r'op_name="([^"]*)"', text)))
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def layout_op_names():
+    """op_name paths of the 1d and 2d solvers, compiled on four virtual
+    CPU devices in a child process (this process keeps one device)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    out = subprocess.run([sys.executable, "-c", _DIST_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+class TestScopes:
+    @pytest.mark.parametrize("driver", sorted(SERIAL_DRIVERS))
+    def test_compiled_text_carries_scopes(self, driver):
+        lower, want = SERIAL_DRIVERS[driver]
+        got = _op_owners(lower().compile().as_text())
+        assert want <= got, f"{driver}: missing {want - got}"
+        assert got <= set(SCOPES)
+
+    @pytest.mark.parametrize("layout", sorted(LAYOUT_DRIVERS))
+    def test_layouts_carry_scopes(self, layout, layout_op_names):
+        want = LAYOUT_DRIVERS[layout]
+        for problem in ("ksvm", "krr"):
+            got = {scopes.owner(p)
+                   for p in layout_op_names[f"{layout}.{problem}"]} - {None}
+            assert want <= got, f"{layout}.{problem}: missing {want - got}"
+
+    @pytest.mark.parametrize("path,want", [
+        ("jit(f)/while/body/kmv/dot_general", "kmv"),
+        ("jit(f)/while/body/scatter/kmv_apply/dot_general:", "kmv_apply"),
+        ("jit(f)/while/body/recurrence/while/body/scatter", "recurrence"),
+        ("jit(kmv)/dot_general:", None),
+        ("jit(f)/vmap()/while/body/psum/psum", "psum"),
+        ("jit(f)/while/body/vmap(cross_block)/jit(gram_slab)/exp",
+         "cross_block"),
+        ("jit(f)/metric_check/vmap(jit(norm))/sqrt", "metric_check"),
+        ("x", None)])
+    def test_owner_is_innermost_scope_before_the_op(self, path, want):
+        assert scopes.owner(path) == want
+
+    def test_unknown_scope_refused(self):
+        with pytest.raises(ValueError, match="unknown scope"):
+            scopes.scoped("kmv_pad")
+
+
+def _lowered_texts(monkeypatch, name):
+    """Run the next fits with ``repro.api.<name>`` recording the text of
+    every program it lowers."""
+    import repro.api as api
+    fn = getattr(api, name)
+    texts = []
+
+    def recording(*args, **kw):
+        texts.append(fn.lower(*args, **kw).as_text())
+        return fn(*args, **kw)
+
+    monkeypatch.setattr(api, name, recording)
+    return texts
+
+
+class TestOneProgram:
+    """Telemetry on and off run one compiled program: the scopes are
+    metadata, and nothing telemetry-dependent reaches the trace."""
+
+    @pytest.mark.parametrize("path,entry,opts", [
+        ("tol", "_krr_serial_tol", dict(tol=1e-10, check_every=4)),
+        ("guarded", "_guarded_serial_chunk",
+         dict(tol=1e-10, check_every=4, guard=True, recompute_every=4))])
+    def test_telemetry_on_off_same_program(self, monkeypatch, path, entry,
+                                           opts):
+        texts = _lowered_texts(monkeypatch, entry)
+        A, y = _problem()
+        for tel in (None, Telemetry(), Telemetry(enabled=False)):
+            KernelRidge(lam=0.5, kernel="rbf", options=SolverOptions(
+                method="sstep", s=4, b=4, max_iters=64, telemetry=tel,
+                **opts)).fit(A, y)
+        assert len(texts) == 3
+        assert texts[0] == texts[1] == texts[2]
+        assert "callback" not in texts[0]
+
+
+class TestSpansOnTheProfilersClock:
+    def test_fit_spans_are_nested_profiler_annotations(self, tmp_path):
+        from jax.profiler import ProfileData
+        A, y = _problem()
+        opts = dict(method="sstep", s=4, b=4, max_iters=32)
+        KernelRidge(lam=0.5, kernel="rbf",
+                    options=SolverOptions(**opts)).fit(A, y)   # compile
+        with jax.profiler.trace(str(tmp_path)):
+            KernelRidge(lam=0.5, kernel="rbf", options=SolverOptions(
+                telemetry=Telemetry(enabled=False), **opts)).fit(A, y)
+            KernelRidge(lam=0.5, kernel="rbf", options=SolverOptions(
+                telemetry=Telemetry(), **opts)).fit(A, y)
+        path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                              "*.xplane.pb"))
+        ev = {}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro."):
+                        ev.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+        # the disabled handle opened none: one of each
+        assert {k: len(v) for k, v in ev.items()
+                if k in ("repro.fit", "repro.schedule", "repro.solve")} \
+            == {"repro.fit": 1, "repro.schedule": 1, "repro.solve": 1}
+        (f0, f1), = ev["repro.fit"]
+        (s0, s1), = ev["repro.schedule"]
+        (v0, v1), = ev["repro.solve"]
+        assert f0 <= s0 <= s1 <= v0 <= v1 <= f1
 
 
 # ---------------------------------------------------------------------------
@@ -243,38 +414,33 @@ class TestInstrumentedFit:
         phases = {s.phase for s in tel.spans}
         assert {"setup", "solve", "fit"} <= phases
         names = [s.name for s in tel.spans]
-        assert "representation_build" in names and "fit" in names
-        # the tolerance path fired traced metric-check marks
-        assert any(m.name == "metric_check" for m in tel.marks)
-        assert len(tel.paired_marks()) >= 1
+        # closed spans land in end order: the fit's parts, then the fit
+        assert names == ["representation_build", "schedule", "solve",
+                         "fit"]
+        fit = tel.spans[-1]
+        assert all(fit.t0 <= s.t0 <= s.t1 <= fit.t1 for s in tel.spans)
+
+    def test_svm_fit_times_its_train_op(self):
+        from repro.api import KernelSVM
+        A, y = _problem()
+        tel = Telemetry()
+        KernelSVM(C=1.0, kernel="rbf", options=SolverOptions(
+            method="sstep", s=4, max_iters=32, telemetry=tel)).fit(
+            A, jnp.sign(y) + (y == 0))
+        names = [s.name for s in tel.spans]
+        assert names == ["representation_build", "schedule", "train_op",
+                         "solve", "fit"]
 
     def test_guarded_fit_counts_corrections(self):
         tel = Telemetry()
         self._fit(tel, guard=True, recompute_every=4)
         c = tel.metrics.counter("repro_guard_corrections_total")
         assert c.value() >= 1
-        assert any(m.name == "drift_correction" for m in tel.marks)
+        assert any(s.name == "guarded_segment" for s in tel.spans)
 
     def test_no_telemetry_fit_unchanged(self):
         res = self._fit(None)
         assert res.telemetry is None
-
-    def test_audit_reconciles_instrumented_fit(self):
-        tel = Telemetry()
-        res = self._fit(tel, guard=True, recompute_every=4)
-        report = audit_fit(res)
-        assert report.rows
-        names = {r.phase for r in report.rows}
-        assert {"setup", "compute", "check"} <= names
-        assert report.measured_total_s > 0
-        d = report.to_dict()
-        assert set(d) >= {"rows", "ratio", "tol", "flagged"}
-        assert "phase" in report.render()
-
-    def test_audit_requires_telemetry(self):
-        res = self._fit(None)
-        with pytest.raises(ValueError, match="telemetry"):
-            audit_fit(res)
 
 
 # ---------------------------------------------------------------------------
@@ -369,12 +535,6 @@ class TestServeMetrics:
 # ---------------------------------------------------------------------------
 
 class TestCLI:
-    def test_report(self, capsys):
-        from repro.obs.__main__ import main
-        assert main(["report", "--m", "48", "--iters", "32"]) == 0
-        out = capsys.readouterr().out
-        assert "phase" in out and "ratio" in out
-
     def test_trace_and_scrape(self, tmp_path, capsys):
         from repro.obs.__main__ import main
         out_path = tmp_path / "t.json"
