@@ -1092,6 +1092,8 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
                 train_op = rep_op.scale_rows(y)
                 if tel is not None:
                     jax.block_until_ready(train_op)
+        # zero rows the solve appends to A, once, to whole KMV blocks
+        pad_rows = 0 if train_op is None else train_op.round_pad_rows
         if opts.guard:
             (alpha, history, converged, rounds_run, iters_run,
              health) = _run_guarded_serial(
@@ -1099,7 +1101,8 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
                 fingerprint=fp, resume=resume)
         elif not want_metric:
             # the span brackets dispatch + completion
-            with _tspan(tel, "solve", "solve", path="fast", s=s):
+            with _tspan(tel, "solve", "solve", path="fast", s=s,
+                        pad_rows=pad_rows):
                 alpha = _serial_fast(problem, A_s, y, a0, schedule,
                                      cfg_s, s, opts.slab_free,
                                      op=train_op)
@@ -1111,7 +1114,8 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
                   else {})
             solve = (_ksvm_serial_tol if problem == "ksvm"
                      else _krr_serial_tol)
-            with _tspan(tel, "solve", "solve", path="tol", s=s):
+            with _tspan(tel, "solve", "solve", path="tol", s=s,
+                        pad_rows=pad_rows):
                 res = solve(A_s, y, a0, schedule, tol, cfg=cfg_s, s=s,
                             check_every=opts.check_every,
                             slab_free=opts.slab_free, op=train_op, **kw)

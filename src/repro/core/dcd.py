@@ -126,6 +126,8 @@ def make_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
     nu, omega = _nu_omega(cfg, C)
     if op is None and gram_fn is None:
         op = (op_factory or ExactGramOperator)(Atil, cfg.kernel)
+    if op is not None:
+        op = op.for_rounds()            # once per solve, outside the loop
 
     if guard:
         def round_fn(carry, i):

@@ -128,6 +128,11 @@ class AllreduceGramOperator:
         self.cfg = cfg
         self.rs = row_sqnorms
 
+    def for_rounds(self):
+        """Loop-ready as built: the round reads the rank's rows as they
+        are (``GramOperator.for_rounds``)."""
+        return self
+
     def round_data(self, idx, x):
         ax, cfg = self.axis_name, self.cfg
         A_loc = self.A_loc

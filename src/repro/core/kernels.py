@@ -113,6 +113,24 @@ def kernel_diag(B: jnp.ndarray, cfg: KernelConfig) -> jnp.ndarray:
     return jnp.ones_like(sq)                     # RBF: K(x, x) = 1
 
 
+def _pad_rows(X: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """``X`` with zero rows appended up to ``rows`` (itself if it has
+    them already)."""
+    pad = rows - X.shape[0]
+    if not pad:
+        return X
+    return jnp.pad(X, ((0, pad),) + ((0, 0),) * (X.ndim - 1))
+
+
+def kmv_pad_rows(m: int, cfg: KernelConfig, block: int = 2048) -> int:
+    """Zero rows the blocked KMV appends to an m-row A: up to a whole
+    number of ``min(block, m)``-row blocks; none for the linear kernel,
+    which contracts without blocks."""
+    if cfg.name == LINEAR:
+        return 0
+    return (-m) % min(block, m)
+
+
 def kmv_slab_free(A: jnp.ndarray, B: jnp.ndarray, X: jnp.ndarray,
                   cfg: KernelConfig, block: int = 2048) -> jnp.ndarray:
     """``U^T X`` with ``U = K(A, B)`` — without an ``m x r`` slab (DESIGN.md
@@ -125,10 +143,13 @@ def kmv_slab_free(A: jnp.ndarray, B: jnp.ndarray, X: jnp.ndarray,
                kernel (``repro.kernels.kmv``) is the fused on-chip version
                of exactly this loop.
 
-    X: (m,) or (m, c) right-hand vectors; returns (r,) / (r, c).
+    X: (m,) or (m, c) right-hand vectors; returns (r,) / (r, c).  A may
+    carry zero rows past X's m (``ExactGramOperator.for_rounds``): X is
+    padded to A's rows, and A is padded only when its rows are not a
+    whole number of blocks.
     """
     vec = X.ndim == 1
-    Xc = X[:, None] if vec else X
+    Xc = _pad_rows(X[:, None] if vec else X, A.shape[0])  # zero rows: no-op
     if cfg.name == LINEAR:
         out = B @ (A.T @ Xc)                            # (r, c)
     else:
@@ -136,9 +157,9 @@ def kmv_slab_free(A: jnp.ndarray, B: jnp.ndarray, X: jnp.ndarray,
         r = B.shape[0]
         c = Xc.shape[1]
         blk = min(block, m)
-        pad = (-m) % blk
-        Ap = jnp.pad(A, ((0, pad), (0, 0)))
-        Xp = jnp.pad(Xc, ((0, pad), (0, 0)))            # zero rows: no-op
+        mp = m + kmv_pad_rows(m, cfg, block)
+        Ap = _pad_rows(A, mp)
+        Xp = _pad_rows(Xc, mp)
         cs = jnp.sum(B * B, axis=1) if cfg.name == RBF else None
 
         def body(acc, chunk):
@@ -173,7 +194,7 @@ def kmv_apply(A: jnp.ndarray, B: jnp.ndarray, w: jnp.ndarray,
     poly/rbf:  blocked scan over m; each (block x r) kernel tile is
                built, applied to w, and discarded.
 
-    w: (r,) or (r, c); returns (m,) / (m, c).
+    w: (r,) or (r, c); returns (m,) / (m, c), one row per row of A.
     """
     vec = w.ndim == 1
     Wc = w[:, None] if vec else w
@@ -182,8 +203,7 @@ def kmv_apply(A: jnp.ndarray, B: jnp.ndarray, w: jnp.ndarray,
     else:
         m, n = A.shape
         blk = min(block, m)
-        pad = (-m) % blk
-        Ap = jnp.pad(A, ((0, pad), (0, 0)))
+        Ap = _pad_rows(A, m + kmv_pad_rows(m, cfg, block))
         cs = jnp.sum(B * B, axis=1) if cfg.name == RBF else None
 
         def body(carry, a_blk):
@@ -300,6 +320,19 @@ class GramOperator:
         """(cross_block, matvec) for one s-step round."""
         return self.cross_block(idx), self.matvec(idx, X)
 
+    @property
+    def round_pad_rows(self) -> int:
+        """Zero rows the loop-ready form (``for_rounds``) carries past
+        the true ``n_samples``."""
+        return 0
+
+    def for_rounds(self) -> "GramOperator":
+        """The operator in the form a round loop reads: built once per
+        solve, before the loop, so that no round repeats work that only
+        depends on the training data.  The round-function factories call
+        it; it is the operator itself unless a backend says otherwise."""
+        return self
+
     # -- guarded-solve surface (repro.resilience, DESIGN.md §12) --------
 
     def apply_at(self, idx: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -319,22 +352,32 @@ class GramOperator:
 class ExactGramOperator(GramOperator):
     """Exact-kernel representation: raw features + kernel config; the
     reductions stream the slab through ``kmv_slab_free`` (or a Pallas KMV
-    backend via ``matvec_impl(A, B, X, cfg)``, see ``kernels.ops``)."""
+    backend via ``matvec_impl(A, B, X, cfg)``, see ``kernels.ops``).
+
+    ``for_rounds()`` gives the loop-ready form: A zero-padded to a whole
+    number of KMV blocks once per solve, so that the blocked KMV pads
+    nothing per round.  ``m`` (static) keeps its true row count; every
+    reduction takes and gives per-row arrays at ``m`` rows, so the
+    padding shows nowhere outside.  ``m`` is None for an unpadded A."""
 
     A: jnp.ndarray
     cfg: KernelConfig
     matvec_impl: Optional[callable] = None
     block: int = 2048
+    m: Optional[int] = None
 
     def rows(self, idx: jnp.ndarray) -> jnp.ndarray:
         return self.A[idx]
 
-    @scoped(KMV)
-    def matvec(self, idx: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
-        B = self.A[idx]
+    def _kmv(self, B: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
+        """``K(A, B)^T X`` through the backend; X has the true m rows."""
         if self.matvec_impl is not None:
             return self.matvec_impl(self.A, B, X, self.cfg)
         return kmv_slab_free(self.A, B, X, self.cfg, block=self.block)
+
+    @scoped(KMV)
+    def matvec(self, idx: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
+        return self._kmv(self.A[idx], X)
 
     @scoped(CROSS_BLOCK)
     def cross_block(self, idx: jnp.ndarray) -> jnp.ndarray:
@@ -346,7 +389,22 @@ class ExactGramOperator(GramOperator):
 
     @property
     def n_samples(self) -> int:
-        return self.A.shape[0]
+        return self.A.shape[0] if self.m is None else self.m
+
+    @property
+    def round_pad_rows(self) -> int:
+        # a backend (matvec_impl) tiles A itself: only the blocked jnp
+        # KMV pads it
+        if self.matvec_impl is not None:
+            return 0
+        return kmv_pad_rows(self.n_samples, self.cfg, self.block)
+
+    def for_rounds(self) -> "ExactGramOperator":
+        rows = self.n_samples + self.round_pad_rows
+        if self.A.shape[0] == rows:
+            return self
+        return dataclasses.replace(self, A=_pad_rows(self.A, rows),
+                                   m=self.n_samples)
 
     @property
     def feature_dim(self) -> int:
@@ -365,14 +423,12 @@ class ExactGramOperator(GramOperator):
         return dataclasses.replace(self, A=y[:, None] * self.A)
 
     def take(self, idx) -> "ExactGramOperator":
-        return dataclasses.replace(self, A=self.A[idx])
+        return dataclasses.replace(self, A=self.A[idx], m=None)
 
     def serve_block(self, Xq: jnp.ndarray, sw: jnp.ndarray) -> jnp.ndarray:
         # K(A, Xq)^T sw == K(Xq, A) @ sw: one KMV with the queries as the
         # sampled rows — slab-free over the (large) training dimension.
-        if self.matvec_impl is not None:
-            return self.matvec_impl(self.A, Xq, sw, self.cfg)
-        return kmv_slab_free(self.A, Xq, sw, self.cfg, block=self.block)
+        return self._kmv(Xq, sw)
 
     @scoped(KMV_APPLY)
     def apply_at(self, idx: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -380,15 +436,12 @@ class ExactGramOperator(GramOperator):
         # matvec_impl accelerates the U^T X reduction only — apply_at's
         # tile loop runs over the m axis instead and its tiles are the
         # same size, so there is nothing kernel-shaped to gain here
-        return kmv_apply(self.A, self.A[idx], w, self.cfg,
-                         block=self.block)
+        out = kmv_apply(self.A, self.A[idx], w, self.cfg, block=self.block)
+        return out[:self.n_samples]
 
     def full_matvec(self, X: jnp.ndarray) -> jnp.ndarray:
         # K symmetric: K @ X == K(A, A)^T X — one full-width KMV
-        if self.matvec_impl is not None:
-            return self.matvec_impl(self.A, self.A, X, self.cfg)
-        return kmv_slab_free(self.A, self.A, X, self.cfg,
-                             block=self.block)
+        return self._kmv(self.A[:self.n_samples], X)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -479,11 +532,8 @@ class LowRankGramOperator(GramOperator):
 
 def _chunk(X, chunk_rows: int):
     """(m, ...) -> (nc, chunk_rows, ...) with a zero-padded tail chunk."""
-    m = X.shape[0]
-    nc = -(-m // chunk_rows)
-    pad = nc * chunk_rows - m
-    if pad:
-        X = jnp.pad(X, ((0, pad),) + ((0, 0),) * (X.ndim - 1))
+    nc = -(-X.shape[0] // chunk_rows)
+    X = _pad_rows(X, nc * chunk_rows)
     return X.reshape((nc, chunk_rows) + X.shape[1:])
 
 
@@ -658,7 +708,7 @@ class StreamingGramOperator(GramOperator):
 
 jax.tree_util.register_dataclass(
     ExactGramOperator, data_fields=("A",),
-    meta_fields=("cfg", "matvec_impl", "block"))
+    meta_fields=("cfg", "matvec_impl", "block", "m"))
 jax.tree_util.register_dataclass(
     LowRankGramOperator, data_fields=("Phi", "fmap"), meta_fields=())
 jax.tree_util.register_dataclass(

@@ -115,6 +115,8 @@ def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
     inv_lam = 1.0 / (cfg.lam if lam is None else lam)
     if op is None and gram_fn is None:
         op = (op_factory or ExactGramOperator)(A, cfg.kernel)
+    if op is not None:
+        op = op.for_rounds()            # once per solve, outside the loop
 
     if guard:
         def round_fn(carry, xs):

@@ -116,7 +116,9 @@ def _make_fleet_round_fn(problem, A_s, y, cfg_s, s, op, params):
     """The vmapped lockstep round: per-member round fns built from the
     SAME factories the facade drives, with the regularizer as the
     batched cfg leaf.  ``op`` (shared, unbatched) is closed over — vmap
-    keeps every reduction that ignores the batch axis un-replicated."""
+    keeps every reduction that ignores the batch axis un-replicated.
+    Its loop-ready form is built here, outside the vmap and the loop."""
+    op = op.for_rounds()
     if problem == "ksvm":
         def member(alpha, p, xs):
             rf = make_sstep_dcd_round_fn(A_s, y, cfg_s, s, op=op, C=p)

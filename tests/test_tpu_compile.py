@@ -15,9 +15,11 @@ in this one file, so that one worker loads the library for all of them.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -109,3 +111,65 @@ def test_kmv_stream_pallas_refused_above_model_budget(one_chip):
     assert not stream_chunk_fits(above, N, R)
     with pytest.raises(Exception, match="vmem"):
         _compile_stream(one_chip, above)
+
+
+# --- the s-step fit's round loop at LIBSVM covtype's shape ----------------
+
+def _loop_computations(hlo: str) -> dict:
+    """{name: body lines} of every computation a ``while`` runs (its
+    body and condition, and whatever they call), in optimized HLO."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line != "}":
+            comps[name].append(line)
+    ref = re.compile(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)"
+                     r"|branch_computations=\{([^}]*)\}")
+
+    def callees(lines):
+        for line in lines:
+            for m in ref.finditer(line):
+                yield from ([m.group(1)] if m.group(1) else
+                            [c.strip().lstrip("%")
+                             for c in m.group(2).split(",")])
+
+    todo = [c for lines in comps.values() for line in lines
+            if " while(" in line
+            for c in re.findall(r"(?:body|condition)=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen and c in comps:
+            seen.add(c)
+            todo.extend(callees(comps[c]))
+    return {c: comps[c] for c in seen}
+
+
+def test_sstep_fit_round_pads_no_a_for_v5e(one_chip):
+    """K-SVM, rbf, s = 16 at 581,012 x 54: the loop-ready operator pads
+    A to whole 2,048-row KMV blocks once, before the round loop, and no
+    round pads an array of A's size again."""
+    from repro.core import SVMConfig, sstep_dcd_ksvm
+    from repro.core.kernels import ExactGramOperator
+
+    m, n, H = 581_012, 54, 16_384
+    cfg = SVMConfig(C=1.0, loss="l1", kernel=KernelConfig("rbf"))
+    hlo = jax.jit(lambda A, y, a0, sched, op: sstep_dcd_ksvm(
+        A, y, a0, sched, cfg, 16, op=op)[0]).lower(
+        _shape(one_chip, m, n), _shape(one_chip, m), _shape(one_chip, m),
+        jax.ShapeDtypeStruct((H,), jnp.int32, sharding=one_chip),
+        ExactGramOperator(_shape(one_chip, m, n), cfg.kernel)
+    ).compile().as_text()
+    a_pad = re.compile(r"^\s*(%\S+) = f32\[([\d,]+)\]\S* pad\(")
+
+    def pads(lines):
+        return [name for line in lines for name, dims in a_pad.findall(line)
+                if np.prod([int(d) for d in dims.split(",")]) >= m * n]
+
+    in_loop = pads(line for lines in _loop_computations(hlo).values()
+                   for line in lines)
+    assert not in_loop, in_loop
+    assert len(pads(hlo.splitlines())) == 1
