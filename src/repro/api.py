@@ -1101,7 +1101,7 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
                 fingerprint=fp, resume=resume)
         elif not want_metric:
             # the span brackets dispatch + completion
-            with _tspan(tel, "solve", "solve", path="fast", s=s,
+            with _tspan(tel, "solve", "solve", path="fast", s=s, b=b,
                         pad_rows=pad_rows):
                 alpha = _serial_fast(problem, A_s, y, a0, schedule,
                                      cfg_s, s, opts.slab_free,
@@ -1114,7 +1114,7 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
                   else {})
             solve = (_ksvm_serial_tol if problem == "ksvm"
                      else _krr_serial_tol)
-            with _tspan(tel, "solve", "solve", path="tol", s=s,
+            with _tspan(tel, "solve", "solve", path="tol", s=s, b=b,
                         pad_rows=pad_rows):
                 res = solve(A_s, y, a0, schedule, tol, cfg=cfg_s, s=s,
                             check_every=opts.check_every,
@@ -1144,7 +1144,7 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
                 problem, A_s, y, a0, schedule, cfg_s, opts, mesh,
                 metric_host, fingerprint=fp, resume=resume)
         elif not want_metric:
-            with _tspan(tel, "solve", "solve", path="dist_fast", s=s,
+            with _tspan(tel, "solve", "solve", path="dist_fast", s=s, b=b,
                         layout=opts.layout):
                 alpha = _dist_chunk(A_s, y, alpha, schedule, **dist_kw)
                 if tel is not None:

@@ -28,7 +28,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+from repro.obs.scopes import (BLOCK_SOLVE, CROSS_BLOCK, KMV, RECURRENCE,
+                              SCATTER, scoped)
 
 from .kernels import ExactGramOperator, KernelConfig
 from .loop import run_rounds
@@ -72,7 +73,8 @@ def _block_solve(Gblk, uTa, alpha_at, y_at, m, inv_lam):
     b = Gblk.shape[0]
     G = inv_lam * Gblk + m * jnp.eye(b, dtype=Gblk.dtype)
     rhs = y_at - m * alpha_at - inv_lam * uTa
-    return jnp.linalg.solve(G, rhs)
+    with jax.named_scope(BLOCK_SOLVE):
+        return jnp.linalg.solve(G, rhs)
 
 
 def make_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,

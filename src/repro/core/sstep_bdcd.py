@@ -38,7 +38,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+from repro.obs.scopes import (BLOCK_SOLVE, CROSS_BLOCK, KMV, RECURRENCE,
+                              SCATTER, scoped)
 
 from .bdcd import KRRConfig
 from .kernels import ExactGramOperator
@@ -76,7 +77,8 @@ def sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat, m, inv_lam,
         rhs = (y_at[j] - m * alpha_at[j] - m * vv
                - inv_lam * jax.lax.dynamic_slice_in_dim(QTalpha, j * b, b)
                - inv_lam * uv)
-        sol = jnp.linalg.solve(G, rhs)
+        with jax.named_scope(BLOCK_SOLVE):
+            sol = jnp.linalg.solve(G, rhs)
         return dalpha.at[j].set(sol * ones[j])
 
     return jax.lax.fori_loop(0, s, inner, jnp.zeros((s, b), dtype))

@@ -21,13 +21,14 @@ KMV = "kmv"                            # U^T X: operators' matvec
 KMV_APPLY = "kmv_apply"                # K[:, idx] @ w: guarded f update
 CROSS_BLOCK = "cross_block"            # the sampled (sb, sb) block
 RECURRENCE = "recurrence"              # the s sequential local solves
+BLOCK_SOLVE = "block_solve"            # K-RR's b x b linear solve
 SCATTER = "scatter"                    # alpha[idx] += (and f +=)
 PSUM = "psum"                          # the distributed collectives
 METRIC_CHECK = "metric_check"          # the tolerance-check branch
 DRIFT_CORRECTION = "drift_correction"  # the guard's residual replacement
 
-SCOPES = (KMV, KMV_APPLY, CROSS_BLOCK, RECURRENCE, SCATTER, PSUM,
-          METRIC_CHECK, DRIFT_CORRECTION)
+SCOPES = (KMV, KMV_APPLY, CROSS_BLOCK, RECURRENCE, BLOCK_SOLVE, SCATTER,
+          PSUM, METRIC_CHECK, DRIFT_CORRECTION)
 
 
 _WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
