@@ -16,6 +16,16 @@ correction sums of paper eq. (3):
 All correction data lives in the (sb x sb) ``Gblk`` and the
 index-collision mask — O((sb)^2) redundant flops, zero communication.
 
+The s systems ``G = m I + K_jj / lam`` are ``Gblk``'s diagonal b x b
+blocks, all known before the first step: only the right-hand sides wait
+on earlier steps.  The round factors them once, batched (Cholesky, and
+the inverses from the factors), under the ``block_solve`` scope, and each
+step applies its block's inverse in float32.  ``K_jj`` is positive
+semidefinite, so each ``G`` is symmetric positive definite with every
+eigenvalue >= m and cond <= 1 + b max K / (lam m): the factorization
+exists, and factoring up front gives the per-step solve's answer to
+rounding.
+
 Slab-free by default (DESIGN.md §2): the ``m x sb`` slab ``Q_k`` is only
 consumed through ``Q^T alpha`` and ``Gblk``, both exposed by
 ``GramOperator`` without materializing ``Q_k``.  ``gram_fn`` forces the
@@ -37,6 +47,7 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.scipy.linalg import cho_solve
 
 from repro.obs.scopes import (BLOCK_SOLVE, CROSS_BLOCK, KMV, RECURRENCE,
                               SCATTER, scoped)
@@ -52,6 +63,9 @@ def sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat, m, inv_lam,
     """The redundant local phase shared by the serial and 2D-distributed
     solvers: ``s`` sequential b x b solves with eq. (3) corrections.
 
+    The s systems are factored once, batched, before the loop (module
+    docstring); step j applies ``G_j^{-1}`` to its right-hand side.
+
     Gblk: (sb, sb), QTalpha: (sb,), alpha_at/y_at: (s, b), flat: (sb,),
     valid: (s,) 1/0 mask for the ragged final round (padded blocks get
     dalpha = 0).  Returns dalpha: (s, b).
@@ -64,6 +78,12 @@ def sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat, m, inv_lam,
     Gblk4 = Gblk.reshape(s, b, s, b)                  # [t, q, j, p]
     eye_b = jnp.eye(b, dtype=dtype)
 
+    with jax.named_scope(BLOCK_SOLVE):
+        # G_j = m I + K_jj / lam: SPD, every eigenvalue >= m
+        G = inv_lam * jnp.einsum("jpjq->jpq", Gblk4) + m * eye_b
+        Ginv = cho_solve(
+            (jnp.linalg.cholesky(G), True), jnp.broadcast_to(eye_b, G.shape))
+
     def inner(j, dalpha):                             # dalpha: (s, b)
         tmask = (jnp.arange(s) < j).astype(dtype)
         prior = dalpha * tmask[:, None]               # zero for t >= j
@@ -71,14 +91,12 @@ def sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat, m, inv_lam,
         vv = jnp.einsum("tq,tqp->p", prior, collide4[:, :, j, :])
         # 1/lam * sum_t U_j^T V_t dalpha_t = Q[idx_t, jb:jb+b]^T dalpha_t
         uv = jnp.einsum("tq,tqp->p", prior, Gblk4[:, :, j, :])
-        Uj_idx = jax.lax.dynamic_slice_in_dim(
-            Gblk4[:, :, j, :].reshape(s * b, b), j * b, b, axis=0)
-        G = inv_lam * Uj_idx + m * eye_b
         rhs = (y_at[j] - m * alpha_at[j] - m * vv
                - inv_lam * jax.lax.dynamic_slice_in_dim(QTalpha, j * b, b)
                - inv_lam * uv)
         with jax.named_scope(BLOCK_SOLVE):
-            sol = jnp.linalg.solve(G, rhs)
+            # multiply-and-sum: float32 on every backend, unlike a dot
+            sol = jnp.sum(Ginv[j] * rhs, axis=1)
         return dalpha.at[j].set(sol * ones[j])
 
     return jax.lax.fori_loop(0, s, inner, jnp.zeros((s, b), dtype))

@@ -1,8 +1,8 @@
 """K-RR's b x b solves under their own scope, and counted on the solve span.
 
 * ``block_solve`` is one of the round's phase names (``repro.obs.SCOPES``);
-* the compiled s-step and classical BDCD programs run their
-  ``jnp.linalg.solve`` under it, inside ``recurrence``, so ``owner``
+* the compiled s-step and classical BDCD programs run their b x b
+  factorizations and solves under it, inside ``recurrence``, so ``owner``
   gives it for the solve's ops and ``recurrence`` keeps the corrections;
   the DCD programs never reach it;
 * the facade's ``solve`` span carries ``b`` (1 for K-SVM) beside ``s``,

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from hlo_loops import factorizations, loop_computations
 from repro.core.kernels import KernelConfig
 from repro.core.perf_model import (STREAM_CHUNK_CANDIDATES,
                                    choose_chunk_rows, stream_chunk_fits)
@@ -115,39 +116,6 @@ def test_kmv_stream_pallas_refused_above_model_budget(one_chip):
 
 # --- the s-step fit's round loop at LIBSVM covtype's shape ----------------
 
-def _loop_computations(hlo: str) -> dict:
-    """{name: body lines} of every computation a ``while`` runs (its
-    body and condition, and whatever they call), in optimized HLO."""
-    comps, name = {}, None
-    for line in hlo.splitlines():
-        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
-        if head:
-            name = head.group(1)
-            comps[name] = []
-        elif name is not None and line != "}":
-            comps[name].append(line)
-    ref = re.compile(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)"
-                     r"|branch_computations=\{([^}]*)\}")
-
-    def callees(lines):
-        for line in lines:
-            for m in ref.finditer(line):
-                yield from ([m.group(1)] if m.group(1) else
-                            [c.strip().lstrip("%")
-                             for c in m.group(2).split(",")])
-
-    todo = [c for lines in comps.values() for line in lines
-            if " while(" in line
-            for c in re.findall(r"(?:body|condition)=%([\w.\-]+)", line)]
-    seen = set()
-    while todo:
-        c = todo.pop()
-        if c not in seen and c in comps:
-            seen.add(c)
-            todo.extend(callees(comps[c]))
-    return {c: comps[c] for c in seen}
-
-
 def test_sstep_fit_round_pads_no_a_for_v5e(one_chip):
     """K-SVM, rbf, s = 16 at 581,012 x 54: the loop-ready operator pads
     A to whole 2,048-row KMV blocks once, before the round loop, and no
@@ -169,7 +137,29 @@ def test_sstep_fit_round_pads_no_a_for_v5e(one_chip):
         return [name for line in lines for name, dims in a_pad.findall(line)
                 if np.prod([int(d) for d in dims.split(",")]) >= m * n]
 
-    in_loop = pads(line for lines in _loop_computations(hlo).values()
+    in_loop = pads(line for lines in loop_computations(hlo).values()
                    for line in lines)
     assert not in_loop, in_loop
     assert len(pads(hlo.splitlines())) == 1
+
+
+def test_sstep_bdcd_round_factors_outside_inner_loop_for_v5e(one_chip):
+    """K-RR, rbf, s = 16, b = 8 at YearPredictionMSD's 463,715 x 90: the
+    round factors its 16 diagonal 8 x 8 blocks in one batched call before
+    the eq. (3) steps, so the inner loop's ``while`` holds no LU or
+    Cholesky."""
+    from repro.core import KRRConfig, sstep_bdcd_krr
+    from repro.core.kernels import ExactGramOperator
+
+    m, n, H, s, b = 463_715, 90, 16_384, 16, 8
+    cfg = KRRConfig(lam=0.1, kernel=KernelConfig("rbf"))
+    hlo = jax.jit(lambda A, y, a0, sched, op: sstep_bdcd_krr(
+        A, y, a0, sched, cfg, s, op=op)[0]).lower(
+        _shape(one_chip, m, n), _shape(one_chip, m), _shape(one_chip, m),
+        jax.ShapeDtypeStruct((H, b), jnp.int32, sharding=one_chip),
+        ExactGramOperator(_shape(one_chip, m, n), cfg.kernel)
+    ).compile().as_text()
+    assert loop_computations(hlo, 2), "no inner loop compiled"
+    assert len(factorizations(hlo, 1)) == 1, factorizations(hlo)
+    assert not factorizations(hlo, 2), factorizations(hlo, 2)
+    assert factorizations(hlo) == factorizations(hlo, 1)
