@@ -3,7 +3,7 @@ representation hierarchy (DESIGN.md §9).
 
 The legacy predict path (``objectives.ksvm_predict`` / ``krr_predict``)
 materializes the dense (q x m) test-kernel slab against the full
-training set — training got slab-free in fig5, serving did not.  This
+training set — training is slab-free, serving was not.  This
 sweep measures queries/second for:
 
   * legacy dense predict (the (q x m) ``gram_slab`` oracle),

@@ -58,8 +58,7 @@ def resilience(fast: bool = False):
     s, b = 8, 8
     kern = KernelConfig("linear")
     A, y = regression_dataset(jax.random.key(0), m, n)
-    base = dict(method="sstep", s=s, b=b, max_iters=H, seed=3,
-                slab_free=True)
+    base = dict(method="sstep", s=s, b=b, max_iters=H, seed=3)
 
     # ---- gate 1: guarded overhead at the autotuned cadence -----------
     plain_opts = SolverOptions(**base)

@@ -9,7 +9,6 @@ Prints ``name,us_per_call,derived`` CSV rows (see common.emit).
   fig3   strong scaling, measured + Hockney-modeled         [paper Figs 3/5/6]
   fig4   running-time breakdown                             [paper Figs 4/7/8]
   table4 block-size ablation                                [paper Table 4]
-  fig5   slab-free vs materialized round (HBM bytes/time)   [EXPERIMENTS §Perf]
   fig6   predict throughput: exact vs low-rank representation,
          batched slab-free vs legacy dense                  [DESIGN §9]
   fig7   sweep throughput: vmapped fleet vs sequential fits,
@@ -39,7 +38,7 @@ def main() -> None:
     from repro.compile_cache import use_compile_cache
     use_compile_cache()
     from benchmarks import (fig1_dcd_convergence, fig2_bdcd_convergence,
-                            fig3_scaling, fig4_breakdown, fig5_slabfree,
+                            fig3_scaling, fig4_breakdown,
                             fig6_predict, fig7_sweep, fig8_resilience,
                             fig9_serve, fig10_streaming,
                             roofline, table4_blocksize)
@@ -73,7 +72,6 @@ def main() -> None:
         "fig3": fig3_scaling.run,
         "fig4": fig4_breakdown.run,
         "table4": table4_blocksize.run,
-        "fig5": fig5_slabfree.run,
         "fig6": fig6_predict.run,
         "fig7": fig7_sweep.run,
         "fig8": fig8_resilience.run,

@@ -7,7 +7,7 @@
   Round fns run under ``lax.scan``: host branching on a tracer raises
   ``TracerBoolConversionError`` at best and silently bakes in the
   trace-time value at worst.  Statically-safe tests are whitelisted:
-  ``is``/``is not`` identity checks (the closure-wiring ``gram_fn is
+  ``is``/``is not`` identity checks (the closure-wiring ``op is
   None`` pattern), comparisons whose subject is static array metadata
   (``.shape``/``.ndim``/``.dtype``/``.size``/``.name``), ``len()``,
   ``isinstance()``, and constants.

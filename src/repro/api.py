@@ -70,7 +70,7 @@ from repro.core import (DIVERGED_NONFINITE, GuardSpec, KernelConfig,
                         KRRConfig, SVMConfig, NO_TOL,
                         ExactGramOperator, StreamingGramOperator,
                         bdcd_krr, block_schedule, coordinate_schedule,
-                        dcd_ksvm, gram_slab, krr_rel_residual,
+                        dcd_ksvm, krr_rel_residual,
                         ksvm_duality_gap, ksvm_duality_gap_lowrank,
                         make_bdcd_round_fn, make_dcd_round_fn,
                         make_sstep_bdcd_round_fn, make_sstep_dcd_round_fn,
@@ -118,9 +118,6 @@ class SolverOptions:
                  "auto" (autotuned over the visible device count).
     mesh:        jax Mesh for 1d/2d; auto-built over the host's devices
                  when None ("model"-major for 1d, "data"-major for 2d).
-    slab_free:   consume kernel slabs through the GramOperator (default);
-                 False forces the materialized-slab parity-oracle path
-                 (serial and 1d only).
     tol:         stop once the convergence metric (duality gap for K-SVM,
                  relative residual for K-RR) falls to tol; 0 disables
                  early stopping.
@@ -149,7 +146,7 @@ class SolverOptions:
                  residual drift, and on divergence auto-falls back along
                  the escalation ladder (halve s -> classical -> f64)
                  from the last good state.  ``FitResult.health`` records
-                 everything observed.  Requires slab_free.
+                 everything observed.
     recompute_every: drift-correction cadence in OUTER rounds — every
                  that many rounds ``f`` is recomputed exactly through
                  the operator (one extra KMV, residual replacement).
@@ -195,7 +192,6 @@ class SolverOptions:
     b: Union[int, str] = 1
     layout: str = "serial"
     mesh: Optional[object] = None
-    slab_free: bool = True
     tol: float = 0.0
     check_every: int = 8
     max_iters: int = 1024
@@ -236,10 +232,6 @@ class SolverOptions:
                              f"chunk size, or {AUTO!r}, got "
                              f"{self.stream!r}")
         if self.stream is not None:
-            if not self.slab_free:
-                raise ValueError("stream= requires slab_free=True: the "
-                                 "streamed representation only exists "
-                                 "behind the GramOperator interface")
             if self.layout not in ("serial", AUTO):
                 raise ValueError(f"stream= requires the serial layout "
                                  f"(the distributed layouts shard the "
@@ -270,10 +262,6 @@ class SolverOptions:
                              f"got {self.probe!r}")
         if not self.tol >= 0.0:
             raise ValueError(f"tol must be >= 0, got {self.tol!r}")
-        if not self.slab_free and self.layout == "2d":
-            raise ValueError("the 2d layout is slab-free by construction; "
-                             "slab_free=False is only meaningful for the "
-                             "serial and 1d layouts")
         if self.approx not in APPROX + (AUTO,):
             raise ValueError(f"approx must be one of {APPROX + (AUTO,)}, "
                              f"got {self.approx!r}")
@@ -290,12 +278,6 @@ class SolverOptions:
                 or self.checkpoint_every < 0:
             raise ValueError(f"checkpoint_every must be an int >= 0, "
                              f"got {self.checkpoint_every!r}")
-        if self.guard and not self.slab_free:
-            raise ValueError("guard=True requires slab_free=True: the "
-                             "guarded round protocol reads the kernel "
-                             "through the GramOperator (the "
-                             "materialized-slab oracle has no residual "
-                             "recurrence to guard)")
         if self.checkpoint_every > 0 and self.checkpoint_dir is None:
             raise ValueError("checkpoint_every > 0 requires "
                              "checkpoint_dir=")
@@ -437,17 +419,13 @@ def _resolve_mesh(opts: SolverOptions):
     return opts.mesh
 
 
-@partial(jax.jit, static_argnames=("cfg", "s", "check_every", "slab_free",
-                                   "lowrank"))
+@partial(jax.jit, static_argnames=("cfg", "s", "check_every", "lowrank"))
 def _ksvm_serial_tol(A, y, a0, schedule, tol, *, cfg: SVMConfig, s: int,
-                     check_every: int, slab_free: bool, op=None,
-                     lowrank: bool = False):
-    gram = None if slab_free else gram_slab
-    op = None if gram is not None else op
+                     check_every: int, op=None, lowrank: bool = False):
     if s == 1:
-        rf, xs = make_dcd_round_fn(A, y, cfg, gram_fn=gram, op=op), schedule
+        rf, xs = make_dcd_round_fn(A, y, cfg, op=op), schedule
     else:
-        rf = make_sstep_dcd_round_fn(A, y, cfg, s, gram_fn=gram, op=op)
+        rf = make_sstep_dcd_round_fn(A, y, cfg, s, op=op)
         xs = pad_rounds(schedule, s)
     # low-rank runs (A is Phi) check the gap through the O(m l) factored
     # form — the generic oracle would build the m x m gram of Phi
@@ -456,15 +434,13 @@ def _ksvm_serial_tol(A, y, a0, schedule, tol, *, cfg: SVMConfig, s: int,
                       metric_fn=lambda a: metric(A, y, a, cfg))
 
 
-@partial(jax.jit, static_argnames=("cfg", "s", "check_every", "slab_free"))
+@partial(jax.jit, static_argnames=("cfg", "s", "check_every"))
 def _krr_serial_tol(A, y, a0, schedule, tol, *, cfg: KRRConfig, s: int,
-                    check_every: int, slab_free: bool, op=None):
-    gram = None if slab_free else gram_slab
-    op = None if gram is not None else op
+                    check_every: int, op=None):
     if s == 1:
-        rf, xs = make_bdcd_round_fn(A, y, cfg, gram_fn=gram, op=op), schedule
+        rf, xs = make_bdcd_round_fn(A, y, cfg, op=op), schedule
     else:
-        rf = make_sstep_bdcd_round_fn(A, y, cfg, s, gram_fn=gram, op=op)
+        rf = make_sstep_bdcd_round_fn(A, y, cfg, s, op=op)
         xs = pad_rounds(schedule, s)
     return run_rounds(rf, a0, xs, tol=tol, check_every=check_every,
                       metric_fn=lambda a: krr_rel_residual(A, y, a, cfg))
@@ -790,7 +766,6 @@ def _run_guarded_dist(problem, A_s, y, a0, schedule, cfg_s,
             alpha_new = _dist_chunk(A_cur, y_cur, alpha, sched_seg,
                                     problem=problem, layout=opts.layout,
                                     mesh=mesh, cfg=cfg_s, s=s_cur,
-                                    slab_free=opts.slab_free,
                                     op_factory=op_factory)
             # the finiteness probe is the chunk's existing sync point;
             # syncing inside the span keeps the interval honest
@@ -872,27 +847,22 @@ def _run_guarded_dist(problem, A_s, y, a0, schedule, cfg_s,
     return alpha, history, converged, rounds_done, pos, health
 
 
-def _serial_fast(problem, A, y, a0, schedule, cfg, s, slab_free, op=None):
-    """tol == 0, no recording: the legacy jitted entrypoints verbatim
-    (driven by the facade-built operator when slab-free)."""
-    gram = None if slab_free else gram_slab
-    op = None if gram is not None else op
+def _serial_fast(problem, A, y, a0, schedule, cfg, s, op=None):
+    """tol == 0, no recording: the legacy jitted entrypoints verbatim,
+    driven by the facade-built operator."""
     if problem == "ksvm":
         if s == 1:
-            return dcd_ksvm(A, y, a0, schedule, cfg, gram_fn=gram,
-                            op=op)[0]
-        return sstep_dcd_ksvm(A, y, a0, schedule, cfg, s, gram_fn=gram,
-                              op=op)[0]
+            return dcd_ksvm(A, y, a0, schedule, cfg, op=op)[0]
+        return sstep_dcd_ksvm(A, y, a0, schedule, cfg, s, op=op)[0]
     if s == 1:
-        return bdcd_krr(A, y, a0, schedule, cfg, gram_fn=gram, op=op)[0]
-    return sstep_bdcd_krr(A, y, a0, schedule, cfg, s, gram_fn=gram,
-                          op=op)[0]
+        return bdcd_krr(A, y, a0, schedule, cfg, op=op)[0]
+    return sstep_bdcd_krr(A, y, a0, schedule, cfg, s, op=op)[0]
 
 
 @partial(jax.jit, static_argnames=("problem", "layout", "mesh", "cfg",
-                                   "s", "slab_free", "op_factory"))
+                                   "s", "op_factory"))
 def _dist_chunk(A, y, a0, schedule, *, problem, layout, mesh, cfg, s,
-                slab_free, op_factory=None):
+                op_factory=None):
     """Jit-cached wrapper around the shard_map solvers: the chunked
     tolerance loop re-enters here once per chunk, and every chunk of the
     same length hits the cache instead of re-tracing the shard_map body
@@ -900,22 +870,20 @@ def _dist_chunk(A, y, a0, schedule, *, problem, layout, mesh, cfg, s,
     ``op_factory`` (static) overrides the per-rank operator build — the
     fault-injection hook for guarded distributed runs."""
     return _dist_call(problem, layout, mesh, A, y, a0, schedule, cfg, s,
-                      slab_free, op_factory)
+                      op_factory)
 
 
 def _dist_call(problem, layout, mesh, A, y, a0, schedule, cfg, s,
-               slab_free, op_factory=None):
+               op_factory=None):
     if problem == "ksvm":
         if layout == "1d":
             return distributed.dist_sstep_dcd_ksvm(
-                mesh, A, y, a0, schedule, cfg, s=s, slab_free=slab_free,
-                op_factory=op_factory)
+                mesh, A, y, a0, schedule, cfg, s=s, op_factory=op_factory)
         return distributed.dist_sstep_dcd_ksvm_2d(
             mesh, A, y, a0, schedule, cfg, s=s, op_factory=op_factory)
     if layout == "1d":
         return distributed.dist_sstep_bdcd_krr(
-            mesh, A, y, a0, schedule, cfg, s=s, slab_free=slab_free,
-            op_factory=op_factory)
+            mesh, A, y, a0, schedule, cfg, s=s, op_factory=op_factory)
     return distributed.dist_sstep_bdcd_krr_2d(
         mesh, A, y, a0, schedule, cfg, s=s, op_factory=op_factory)
 
@@ -1083,17 +1051,16 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
         P = 1
         # the training operator (K-SVM: diag(y)-scaled rows — a second
         # (m, n)/(m, l) buffer) is built ONLY where it is consumed: the
-        # serial slab-free paths.  The shard_map bodies rebuild their
-        # per-rank operators from their own shards, and the
-        # materialized-slab oracle bypasses operators entirely.
-        train_op = rep_op if opts.slab_free else None
-        if opts.slab_free and problem == "ksvm":
+        # serial paths.  The shard_map bodies rebuild their per-rank
+        # operators from their own shards.
+        train_op = rep_op
+        if problem == "ksvm":
             with _tspan(tel, "train_op", "setup"):
                 train_op = rep_op.scale_rows(y)
                 if tel is not None:
                     jax.block_until_ready(train_op)
         # zero rows the solve appends to A, once, to whole KMV blocks
-        pad_rows = 0 if train_op is None else train_op.round_pad_rows
+        pad_rows = train_op.round_pad_rows
         if opts.guard:
             (alpha, history, converged, rounds_run, iters_run,
              health) = _run_guarded_serial(
@@ -1104,8 +1071,7 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
             with _tspan(tel, "solve", "solve", path="fast", s=s, b=b,
                         pad_rows=pad_rows):
                 alpha = _serial_fast(problem, A_s, y, a0, schedule,
-                                     cfg_s, s, opts.slab_free,
-                                     op=train_op)
+                                     cfg_s, s, op=train_op)
                 if tel is not None:
                     jax.block_until_ready(alpha)
             rounds_run = -(-H // s)
@@ -1117,8 +1083,8 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
             with _tspan(tel, "solve", "solve", path="tol", s=s, b=b,
                         pad_rows=pad_rows):
                 res = solve(A_s, y, a0, schedule, tol, cfg=cfg_s, s=s,
-                            check_every=opts.check_every,
-                            slab_free=opts.slab_free, op=train_op, **kw)
+                            check_every=opts.check_every, op=train_op,
+                            **kw)
                 alpha = res.state
                 # rounds_run is the host sync; inside the span so the
                 # measured interval covers the whole while-loop
@@ -1137,7 +1103,7 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
              else mesh.shape["data"] * mesh.shape["model"])
         alpha = a0
         dist_kw = dict(problem=problem, layout=opts.layout, mesh=mesh,
-                       cfg=cfg_s, s=s, slab_free=opts.slab_free)
+                       cfg=cfg_s, s=s)
         if opts.guard:
             (alpha, history, converged, rounds_run, iters_run,
              health) = _run_guarded_dist(

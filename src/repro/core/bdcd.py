@@ -10,9 +10,9 @@ sub-system and solves it exactly:
     dalpha = G_k^{-1}(V_k^T y - m V_k^T alpha - (1/lambda) U_k^T alpha)
 
 The ``m x b`` slab ``U_k = K(A, V_k^T A)`` only enters through
-``U_k^T alpha`` and its sampled b x b block, so the default path is
-slab-free via ``GramOperator`` (DESIGN.md §2); ``gram_fn`` forces the
-legacy materialized-slab path (the parity oracle).
+``U_k^T alpha`` and its sampled b x b block, so the solver reads both
+slab-free through a ``GramOperator`` (DESIGN.md §2); the tests'
+materialized reference is ``repro.kernels.ref.SlabGramOperator``.
 
 Prefer the ``repro.api`` facade (``KernelRidge`` with
 ``SolverOptions(method="classical", b=...)``) over calling this
@@ -28,8 +28,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs.scopes import (BLOCK_SOLVE, CROSS_BLOCK, KMV, RECURRENCE,
-                              SCATTER, scoped)
+from repro.obs.scopes import BLOCK_SOLVE, RECURRENCE, SCATTER, scoped
 
 from .kernels import ExactGramOperator, KernelConfig
 from .loop import run_rounds
@@ -78,7 +77,6 @@ def _block_solve(Gblk, uTa, alpha_at, y_at, m, inv_lam):
 
 
 def make_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
-                       gram_fn: Optional[Callable] = None,
                        op_factory: Optional[Callable] = None,
                        op=None, lam=None, guard: bool = False) -> Callable:
     """``round_fn(alpha, idx) -> alpha`` for ``loop.run_rounds``: one
@@ -96,19 +94,15 @@ def make_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
     maintained by ``f += K[:, idx] @ dalpha`` — the same m x b block
     the round already evaluates; ``U^T alpha`` becomes the free gather
     ``f[idx]``, and drift correction splices an exactly recomputed
-    ``f`` back in; DESIGN.md §12).  Requires the operator path."""
-    if sum(x is not None for x in (gram_fn, op_factory, op)) > 1:
-        raise ValueError("pass at most one of gram_fn (materialized "
-                         "slab), op_factory, or op (prebuilt operator)")
-    if guard and gram_fn is not None:
-        raise ValueError("guard=True requires the GramOperator path "
-                         "(gram_fn= is the legacy materialized oracle)")
+    ``f`` back in; DESIGN.md §12)."""
+    if op_factory is not None and op is not None:
+        raise ValueError("pass at most one of op_factory or op "
+                         "(prebuilt operator)")
     m = A.shape[0]
     inv_lam = 1.0 / (cfg.lam if lam is None else lam)
-    if op is None and gram_fn is None:
+    if op is None:
         op = (op_factory or ExactGramOperator)(A, cfg.kernel)
-    if op is not None:
-        op = op.for_rounds()            # once per solve, outside the loop
+    op = op.for_rounds()                # once per solve, outside the loop
 
     if guard:
         def round_fn(carry, idx):             # idx: (b,)
@@ -124,14 +118,7 @@ def make_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
         return round_fn
 
     def round_fn(alpha, idx):                 # idx: (b,)
-        if gram_fn is not None:               # materialized m x b slab
-            with jax.named_scope(KMV):
-                U = gram_fn(A, A[idx], cfg.kernel)
-                uTa = U.T @ alpha
-            with jax.named_scope(CROSS_BLOCK):
-                Gblk = U[idx, :]
-        else:                                 # slab-free operator path
-            Gblk, uTa = op.round_data(idx, alpha)
+        Gblk, uTa = op.round_data(idx, alpha)
         dalpha = _block_solve(Gblk, uTa, alpha[idx], y[idx], m, inv_lam)
         with jax.named_scope(SCATTER):
             return alpha.at[idx].add(dalpha)
@@ -139,22 +126,19 @@ def make_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
     return round_fn
 
 
-# repro: noqa[CHK-STATIC] gram_fn/op_factory are module-level functions
+# repro: noqa[CHK-STATIC] op_factory is a module-level class or function
 #   (or None) at every call site; passing a fresh closure retraces by
-#   design — it is the documented parity-oracle escape hatch.
-@partial(jax.jit, static_argnames=("cfg", "record_every", "gram_fn",
-                                   "op_factory"))
+#   design — it is how tests swap in a reference operator.
+@partial(jax.jit, static_argnames=("cfg", "record_every", "op_factory"))
 def bdcd_krr(A: jnp.ndarray, y: jnp.ndarray, alpha0: jnp.ndarray,
              schedule: jnp.ndarray, cfg: KRRConfig,
              record_every: int = 0,
-             gram_fn: Optional[Callable] = None,
              op_factory: Optional[Callable] = None,
              op=None,
              ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Run Algorithm 3 for H = schedule.shape[0] iterations.  ``op``
     injects a prebuilt operator (pytree, crosses jit as data)."""
-    round_fn = make_bdcd_round_fn(A, y, cfg, gram_fn=gram_fn,
-                                  op_factory=op_factory, op=op)
+    round_fn = make_bdcd_round_fn(A, y, cfg, op_factory=op_factory, op=op)
     res = run_rounds(round_fn, alpha0, schedule,
                      record_state=bool(record_every))
     if record_every:

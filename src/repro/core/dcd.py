@@ -12,9 +12,9 @@ all-reduce per iteration, which is exactly the bottleneck the s-step
 variant (``sstep_dcd.py``) removes.
 
 The column is only ever consumed through ``u_k^T alpha`` and ``u_k[i_k]``
-(= K(a_i, a_i)), so the default path reads both through a slab-free
-``GramOperator`` (DESIGN.md §2); ``gram_fn`` forces the legacy
-materialized-column path, kept as the parity oracle.
+(= K(a_i, a_i)), so the solver reads both through a slab-free
+``GramOperator`` (DESIGN.md §2).  The tests' materialized reference is
+``repro.kernels.ref.SlabGramOperator``, passed as ``op_factory``.
 
 Prefer the ``repro.api`` facade (``KernelSVM`` with
 ``SolverOptions(method="classical")``) over calling this entrypoint
@@ -30,7 +30,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+from repro.obs.scopes import RECURRENCE, SCATTER, scoped
 
 from .kernels import ExactGramOperator, KernelConfig
 from .loop import run_rounds
@@ -90,7 +90,6 @@ def _dcd_theta(alpha_i, g, eta, nu):
 
 
 def make_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
-                      gram_fn: Optional[Callable] = None,
                       op_factory: Optional[Callable] = None,
                       op=None, C=None, guard: bool = False) -> Callable:
     """``round_fn(alpha, i) -> alpha`` for ``loop.run_rounds``: one
@@ -114,20 +113,16 @@ def make_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
     the per-round kernel work is unchanged; DESIGN.md §12).  ``u^T
     alpha`` then becomes the free gather ``f[i]``, and drift correction
     can splice an exactly recomputed ``f`` back in (residual
-    replacement).  Requires the operator path (no ``gram_fn``).
+    replacement).
     """
-    if sum(x is not None for x in (gram_fn, op_factory, op)) > 1:
-        raise ValueError("pass at most one of gram_fn (materialized "
-                         "slab), op_factory, or op (prebuilt operator)")
-    if guard and gram_fn is not None:
-        raise ValueError("guard=True requires the GramOperator path "
-                         "(gram_fn= is the legacy materialized oracle)")
+    if op_factory is not None and op is not None:
+        raise ValueError("pass at most one of op_factory or op "
+                         "(prebuilt operator)")
     Atil = y[:, None] * A                       # diag(y) @ A
     nu, omega = _nu_omega(cfg, C)
-    if op is None and gram_fn is None:
+    if op is None:
         op = (op_factory or ExactGramOperator)(Atil, cfg.kernel)
-    if op is not None:
-        op = op.for_rounds()            # once per solve, outside the loop
+    op = op.for_rounds()                # once per solve, outside the loop
 
     if guard:
         def round_fn(carry, i):
@@ -143,18 +138,9 @@ def make_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
         return round_fn
 
     def round_fn(alpha, i):
-        idx = i[None]
-        if gram_fn is not None:                 # materialized m x 1 column
-            with jax.named_scope(KMV):
-                u = gram_fn(Atil, Atil[idx], cfg.kernel)[:, 0]
-                uTa = u @ alpha
-            with jax.named_scope(CROSS_BLOCK):
-                eta = u[i] + omega
-            g = uTa - 1.0 + omega * alpha[i]
-        else:                                   # slab-free operator path
-            G, uTa = op.round_data(idx, alpha)  # (1, 1), (1,)
-            eta = G[0, 0] + omega
-            g = uTa[0] - 1.0 + omega * alpha[i]
+        G, uTa = op.round_data(i[None], alpha)  # (1, 1), (1,)
+        eta = G[0, 0] + omega
+        g = uTa[0] - 1.0 + omega * alpha[i]
         theta = _dcd_theta(alpha[i], g, eta, nu)
         with jax.named_scope(SCATTER):
             return alpha.at[i].add(theta)
@@ -162,15 +148,13 @@ def make_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
     return round_fn
 
 
-# repro: noqa[CHK-STATIC] gram_fn/op_factory are module-level functions
+# repro: noqa[CHK-STATIC] op_factory is a module-level class or function
 #   (or None) at every call site; passing a fresh closure retraces by
-#   design — it is the documented parity-oracle escape hatch.
-@partial(jax.jit, static_argnames=("cfg", "record_every", "gram_fn",
-                                   "op_factory"))
+#   design — it is how tests swap in a reference operator.
+@partial(jax.jit, static_argnames=("cfg", "record_every", "op_factory"))
 def dcd_ksvm(A: jnp.ndarray, y: jnp.ndarray, alpha0: jnp.ndarray,
              schedule: jnp.ndarray, cfg: SVMConfig,
              record_every: int = 0,
-             gram_fn: Optional[Callable] = None,
              op_factory: Optional[Callable] = None,
              op=None,
              ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
@@ -181,8 +165,7 @@ def dcd_ksvm(A: jnp.ndarray, y: jnp.ndarray, alpha0: jnp.ndarray,
     it crosses the jit boundary as data) injects a prebuilt, already
     row-scaled training operator; see ``make_dcd_round_fn``.
     """
-    round_fn = make_dcd_round_fn(A, y, cfg, gram_fn=gram_fn,
-                                 op_factory=op_factory, op=op)
+    round_fn = make_dcd_round_fn(A, y, cfg, op_factory=op_factory, op=op)
     res = run_rounds(round_fn, alpha0, schedule,
                      record_state=bool(record_every))
     if record_every:

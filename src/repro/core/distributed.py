@@ -60,37 +60,6 @@ def _psum(x, axis_name):
         return jax.lax.psum(x, axis_name)
 
 
-def make_allreduce_gram(axis_name: str, row_sqnorms=None):
-    """Feature-partitioned MATERIALIZED gram slab (legacy / parity oracle):
-    partial GEMM on local columns, one all-reduce (== the paper's
-    MPI_Allreduce), then the nonlinear epilogue applied redundantly on
-    every rank (as in Thm 1/2 proofs).  The slab-free operator below is
-    the default; this path survives as ``slab_free=False``.
-
-    §Perf-paper optimization: for RBF, ``row_sqnorms`` (the psummed
-    ||a_i||^2, computed ONCE per solve — they are loop-invariant) removes
-    the per-round (m,) norm psum, and the remaining (s*b,) B-norm vector
-    is FUSED into the slab all-reduce (concat one extra row), so every
-    round issues exactly ONE collective — the paper's ideal schedule.
-    """
-
-    def gram(A_loc, B_loc, cfg: KernelConfig):
-        dots_part = A_loc @ B_loc.T                       # (m, sb) partial
-        if cfg.name != RBF:
-            return apply_epilogue(_psum(dots_part, axis_name), cfg)
-        cs_part = jnp.sum(B_loc * B_loc, axis=1)[None, :]  # (1, sb)
-        if row_sqnorms is not None:
-            packed = _psum(jnp.concatenate([dots_part, cs_part], axis=0),
-                           axis_name)
-            return apply_epilogue(packed[:-1], cfg, row_sqnorms,
-                                  packed[-1])
-        rs = _psum(jnp.sum(A_loc * A_loc, axis=1), axis_name)
-        cs = _psum(cs_part[0], axis_name)
-        return apply_epilogue(_psum(dots_part, axis_name), cfg, rs, cs)
-
-    return gram
-
-
 class AllreduceGramOperator:
     """Slab-free ``GramOperator`` for the paper's 1D-column layout.
 
@@ -173,12 +142,11 @@ def _psummed_row_sqnorms(A_loc, cfg: KernelConfig, axis_name: str):
 
 def dist_sstep_dcd_ksvm(mesh: Mesh, A, y, alpha0, schedule,
                         cfg: SVMConfig, s: int, axis_name: str = "model",
-                        slab_free: bool = True, op_factory=None):
+                        op_factory=None):
     """s-step DCD for K-SVM with A in 1D-column layout over ``axis_name``.
 
     A may be passed as a global array; it is sharded on features by the
-    in_spec.  Returns the replicated final alpha.  ``slab_free=False``
-    selects the legacy materialized-slab all-reduce path (parity oracle).
+    in_spec.  Returns the replicated final alpha.
 
     ``op_factory(Atil_loc, kernel_cfg)`` injects a custom per-rank
     ``GramOperator`` built from the LOCAL (already diag(y)-scaled) column
@@ -196,34 +164,30 @@ def dist_sstep_dcd_ksvm(mesh: Mesh, A, y, alpha0, schedule,
     def run(A_loc, y_r, a0_r, sched_r):
         Atil_loc = y_r[:, None] * A_loc
         rs = _psummed_row_sqnorms(Atil_loc, cfg.kernel, axis_name)
-        if op_factory is not None:
-            kw = {"op_factory": op_factory}
-        elif slab_free:
-            def default_factory(Atil, kcfg):
-                return AllreduceGramOperator(axis_name, Atil, kcfg, rs)
-            kw = {"op_factory": default_factory}
-        else:
-            kw = {"gram_fn": make_allreduce_gram(axis_name, row_sqnorms=rs)}
+
+        def default_factory(Atil, kcfg):
+            return AllreduceGramOperator(axis_name, Atil, kcfg, rs)
+
         # pass A_loc (sstep solver re-applies diag(y), idempotent w/ ones)
-        out, _ = sstep_dcd_ksvm(A_loc, y_r, a0_r, sched_r, cfg, s, **kw)
+        out, _ = sstep_dcd_ksvm(A_loc, y_r, a0_r, sched_r, cfg, s,
+                                op_factory=op_factory or default_factory)
         return out
 
     return run(A, y, alpha0, schedule)
 
 
 def dist_dcd_ksvm(mesh: Mesh, A, y, alpha0, schedule,
-                  cfg: SVMConfig, axis_name: str = "model",
-                  slab_free: bool = True):
+                  cfg: SVMConfig, axis_name: str = "model"):
     """Classical DCD baseline (communicates every iteration): implemented
     as s-step with s=1, which degenerates to Algorithm 1's schedule —
     one m-word psum per iteration."""
     return dist_sstep_dcd_ksvm(mesh, A, y, alpha0, schedule, cfg, s=1,
-                               axis_name=axis_name, slab_free=slab_free)
+                               axis_name=axis_name)
 
 
 def dist_sstep_bdcd_krr(mesh: Mesh, A, y, alpha0, schedule,
                         cfg: KRRConfig, s: int, axis_name: str = "model",
-                        slab_free: bool = True, op_factory=None):
+                        op_factory=None):
     """s-step BDCD for K-RR, 1D-column layout.  ``op_factory(A_loc,
     kernel_cfg)`` injects a custom per-rank operator (see
     ``dist_sstep_dcd_ksvm``); low-rank runs pass ``A = Phi`` + linear
@@ -233,26 +197,22 @@ def dist_sstep_bdcd_krr(mesh: Mesh, A, y, alpha0, schedule,
              check_vma=False)
     def run(A_loc, y_r, a0_r, sched_r):
         rs = _psummed_row_sqnorms(A_loc, cfg.kernel, axis_name)
-        if op_factory is not None:
-            kw = {"op_factory": op_factory}
-        elif slab_free:
-            def default_factory(A_, kcfg):
-                return AllreduceGramOperator(axis_name, A_, kcfg, rs)
-            kw = {"op_factory": default_factory}
-        else:
-            kw = {"gram_fn": make_allreduce_gram(axis_name, row_sqnorms=rs)}
-        out, _ = sstep_bdcd_krr(A_loc, y_r, a0_r, sched_r, cfg, s, **kw)
+
+        def default_factory(A_, kcfg):
+            return AllreduceGramOperator(axis_name, A_, kcfg, rs)
+
+        out, _ = sstep_bdcd_krr(A_loc, y_r, a0_r, sched_r, cfg, s,
+                                op_factory=op_factory or default_factory)
         return out
 
     return run(A, y, alpha0, schedule)
 
 
 def dist_bdcd_krr(mesh: Mesh, A, y, alpha0, schedule,
-                  cfg: KRRConfig, axis_name: str = "model",
-                  slab_free: bool = True):
+                  cfg: KRRConfig, axis_name: str = "model"):
     """Classical BDCD baseline — one (m x b)-word psum per iteration."""
     return dist_sstep_bdcd_krr(mesh, A, y, alpha0, schedule, cfg, s=1,
-                               axis_name=axis_name, slab_free=slab_free)
+                               axis_name=axis_name)
 
 
 # --------------------------------------------------------------------------

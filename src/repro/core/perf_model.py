@@ -441,43 +441,6 @@ def choose_serve_plan(m: int, n: int, kernel: str, *, rate_qps: float,
                      frontier=tuple(frontier))
 
 
-# --------------------------------------------------------------------------
-# On-chip traffic model (EXPERIMENTS.md §Perf): HBM bytes per outer round.
-# The network Hockney model above prices the collective; these two price
-# the local memory system, where the materialized m x sb slab is the
-# dominant term the slab-free KMV kernel deletes.
-# --------------------------------------------------------------------------
-
-def slab_round_hbm_bytes(m: int, n: int, sb: int, c: int = 1,
-                         word: int = 4) -> int:
-    """Materialized-slab s-step round (fused-epilogue gram kernel +
-    separate consumers):
-
-      gram:     read A (m*n) + read B (sb*n), write slab (m*sb)
-      U^T x:    re-read slab (m*sb) + read x (c*m), write (c*sb)
-      Gblk:     gather sb slab rows (sb*sb)
-
-    The 2*m*sb slab round-trip dominates for m >> n, sb.
-    """
-    gram = m * n + sb * n + m * sb
-    consume = m * sb + c * m + c * sb + sb * sb
-    return word * (gram + consume)
-
-
-def kmv_round_hbm_bytes(m: int, n: int, sb: int, c: int = 1,
-                        word: int = 4) -> int:
-    """Slab-free s-step round (fused KMV kernel + small cross-block gram):
-
-      KMV:      read A (m*n) + read B (sb*n) + read x (c*m), write (c*sb)
-      Gblk:     read B twice (2*sb*n), write sb*sb
-
-    Zero m x sb traffic: the slab lives only in VMEM tiles.
-    """
-    kmv = m * n + sb * n + c * m + c * sb
-    cross = 2 * sb * n + sb * sb
-    return word * (kmv + cross)
-
-
 HOST_MEMORY_BYTES = 16 * 2 ** 30   # budget modeled for a CPU backend,
                                    # which reports no device limit
 

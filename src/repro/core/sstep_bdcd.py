@@ -26,10 +26,10 @@ eigenvalue >= m and cond <= 1 + b max K / (lam m): the factorization
 exists, and factoring up front gives the per-step solve's answer to
 rounding.
 
-Slab-free by default (DESIGN.md §2): the ``m x sb`` slab ``Q_k`` is only
-consumed through ``Q^T alpha`` and ``Gblk``, both exposed by
-``GramOperator`` without materializing ``Q_k``.  ``gram_fn`` forces the
-legacy materialized-slab path (parity oracle / paper-faithful baseline).
+Slab-free (DESIGN.md §2): the ``m x sb`` slab ``Q_k`` is only consumed
+through ``Q^T alpha`` and ``Gblk``, both exposed by ``GramOperator``
+without materializing ``Q_k``.  The tests' materialized reference is
+``repro.kernels.ref.SlabGramOperator``, passed as ``op_factory``.
 
 Ragged schedules are fine: ``H % s != 0`` runs a final short round via the
 pad-and-mask round protocol (``loop.pad_rounds``); padded blocks produce
@@ -49,8 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import cho_solve
 
-from repro.obs.scopes import (BLOCK_SOLVE, CROSS_BLOCK, KMV, RECURRENCE,
-                              SCATTER, scoped)
+from repro.obs.scopes import BLOCK_SOLVE, RECURRENCE, SCATTER, scoped
 
 from .bdcd import KRRConfig
 from .kernels import ExactGramOperator
@@ -104,7 +103,6 @@ def sstep_bdcd_inner(Gblk, QTalpha, alpha_at, y_at, flat, m, inv_lam,
 
 def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
                              s: int,
-                             gram_fn: Optional[Callable] = None,
                              op_factory: Optional[Callable] = None,
                              op=None, lam=None, guard: bool = False,
                              ) -> Callable:
@@ -124,19 +122,15 @@ def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
     the fused KMV already evaluates; ``Q^T alpha`` becomes the free
     gather ``f[flat]``, and drift correction splices an exactly
     recomputed ``f`` back in — residual replacement for the s-step
-    recurrence; DESIGN.md §12).  Requires the operator path."""
-    if sum(x is not None for x in (gram_fn, op_factory, op)) > 1:
-        raise ValueError("pass at most one of gram_fn (materialized "
-                         "slab), op_factory, or op (prebuilt operator)")
-    if guard and gram_fn is not None:
-        raise ValueError("guard=True requires the GramOperator path "
-                         "(gram_fn= is the legacy materialized oracle)")
+    recurrence; DESIGN.md §12)."""
+    if op_factory is not None and op is not None:
+        raise ValueError("pass at most one of op_factory or op "
+                         "(prebuilt operator)")
     m = A.shape[0]
     inv_lam = 1.0 / (cfg.lam if lam is None else lam)
-    if op is None and gram_fn is None:
+    if op is None:
         op = (op_factory or ExactGramOperator)(A, cfg.kernel)
-    if op is not None:
-        op = op.for_rounds()            # once per solve, outside the loop
+    op = op.for_rounds()                # once per solve, outside the loop
 
     if guard:
         def round_fn(carry, xs):
@@ -162,14 +156,7 @@ def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
         b = idx.shape[1]
         flat = idx.reshape(s * b)
         # --- communication phase ----------------------------------------
-        if gram_fn is not None:                # materialized m x sb slab
-            with jax.named_scope(KMV):
-                Q = gram_fn(A, A[flat], cfg.kernel)
-                QTalpha = Q.T @ alpha          # (s*b,)
-            with jax.named_scope(CROSS_BLOCK):
-                Gblk = Q[flat, :]              # (s*b, s*b)
-        else:                                  # slab-free operator path
-            Gblk, QTalpha = op.round_data(flat, alpha)
+        Gblk, QTalpha = op.round_data(flat, alpha)   # (sb, sb), (sb,)
         y_at = y[idx]                          # (s, b)
         alpha_at = alpha[idx]                  # (s, b)
 
@@ -182,15 +169,13 @@ def make_sstep_bdcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: KRRConfig,
     return round_fn
 
 
-# repro: noqa[CHK-STATIC] gram_fn/op_factory are module-level functions
+# repro: noqa[CHK-STATIC] op_factory is a module-level class or function
 #   (or None) at every call site; passing a fresh closure retraces by
-#   design — it is the documented parity-oracle escape hatch.
-@partial(jax.jit, static_argnames=("cfg", "s", "record_rounds", "gram_fn",
-                                   "op_factory"))
+#   design — it is how tests swap in a reference operator.
+@partial(jax.jit, static_argnames=("cfg", "s", "record_rounds", "op_factory"))
 def sstep_bdcd_krr(A: jnp.ndarray, y: jnp.ndarray, alpha0: jnp.ndarray,
                    schedule: jnp.ndarray, cfg: KRRConfig, s: int,
                    record_rounds: bool = False,
-                   gram_fn: Optional[Callable] = None,
                    op_factory: Optional[Callable] = None,
                    op=None,
                    ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
@@ -198,8 +183,8 @@ def sstep_bdcd_krr(A: jnp.ndarray, y: jnp.ndarray, alpha0: jnp.ndarray,
     ``bdcd.block_schedule``; ragged H (H % s != 0) runs a masked final
     short round.  ``op`` (a pytree — crosses the jit boundary as data)
     injects a prebuilt operator; see ``make_sstep_bdcd_round_fn``."""
-    round_fn = make_sstep_bdcd_round_fn(A, y, cfg, s, gram_fn=gram_fn,
-                                        op_factory=op_factory, op=op)
+    round_fn = make_sstep_bdcd_round_fn(A, y, cfg, s, op_factory=op_factory,
+                                        op=op)
     xs = pad_rounds(schedule, s)
     res = run_rounds(round_fn, alpha0, xs, record_state=record_rounds)
     return res.state, (res.state_hist if record_rounds else None)

@@ -12,12 +12,11 @@ then runs the ``s`` scalar sub-problem solves sequentially with gradient
 corrections (paper lines 14-23), touching only O(s^2) data and **no
 communication**.
 
-Slab-free by default (DESIGN.md §2): the ``m x s`` slab ``U_k`` is only
-ever consumed through ``U_k^T alpha`` and its sampled ``s x s`` cross
-block, so the solver reads both through a ``GramOperator`` and the slab
-never exists in HBM.  Pass ``gram_fn`` (e.g. ``core.kernels.gram_slab`` or
-the Pallas fused gram kernel) to force the legacy materialized-slab path —
-kept as the parity oracle and the paper-faithful baseline.
+Slab-free (DESIGN.md §2): the ``m x s`` slab ``U_k`` is only ever
+consumed through ``U_k^T alpha`` and its sampled ``s x s`` cross block,
+so the solver reads both through a ``GramOperator`` and the slab never
+exists in HBM.  The tests' materialized reference is
+``repro.kernels.ref.SlabGramOperator``, passed as ``op_factory``.
 
 Ragged schedules are fine: ``H % s != 0`` runs a final short round via the
 pad-and-mask round protocol (``loop.pad_rounds``); padded slots produce
@@ -36,7 +35,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs.scopes import CROSS_BLOCK, KMV, RECURRENCE, SCATTER, scoped
+from repro.obs.scopes import RECURRENCE, SCATTER, scoped
 
 from .dcd import SVMConfig
 from .kernels import ExactGramOperator
@@ -80,7 +79,6 @@ def sstep_dcd_inner(G0, u_dot_alpha, alpha_at, idx_s, nu, omega, s,
 
 def make_sstep_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
                             s: int,
-                            gram_fn: Optional[Callable] = None,
                             op_factory: Optional[Callable] = None,
                             op=None, C=None, guard: bool = False,
                             ) -> Callable:
@@ -103,21 +101,17 @@ def make_sstep_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
     evaluates, so per-round kernel work is unchanged; DESIGN.md §12).
     ``U^T alpha`` becomes the free gather ``f[idx_s]`` and drift
     correction can splice an exactly recomputed ``f`` back in (residual
-    replacement, Devarakonda et al. 2016).  Requires the operator path.
+    replacement, Devarakonda et al. 2016).
     """
-    if sum(x is not None for x in (gram_fn, op_factory, op)) > 1:
-        raise ValueError("pass at most one of gram_fn (materialized "
-                         "slab), op_factory, or op (prebuilt operator)")
-    if guard and gram_fn is not None:
-        raise ValueError("guard=True requires the GramOperator path "
-                         "(gram_fn= is the legacy materialized oracle)")
+    if op_factory is not None and op is not None:
+        raise ValueError("pass at most one of op_factory or op "
+                         "(prebuilt operator)")
     from .dcd import _nu_omega
     Atil = y[:, None] * A
     nu, omega = _nu_omega(cfg, C)
-    if op is None and gram_fn is None:
+    if op is None:
         op = (op_factory or ExactGramOperator)(Atil, cfg.kernel)
-    if op is not None:
-        op = op.for_rounds()            # once per solve, outside the loop
+    op = op.for_rounds()                # once per solve, outside the loop
 
     if guard:
         def round_fn(carry, xs):
@@ -136,14 +130,7 @@ def make_sstep_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
     def round_fn(alpha, xs):
         idx_s, valid = xs
         # --- communication phase: one fused round, one (would-be) psum ---
-        if gram_fn is not None:                  # materialized m x s slab
-            with jax.named_scope(KMV):
-                U = gram_fn(Atil, Atil[idx_s], cfg.kernel)
-                u_dot_alpha = U.T @ alpha        # (s,)
-            with jax.named_scope(CROSS_BLOCK):
-                G0 = U[idx_s, :]                 # V_k^T U_k, (s, s)
-        else:                                    # slab-free operator path
-            G0, u_dot_alpha = op.round_data(idx_s, alpha)
+        G0, u_dot_alpha = op.round_data(idx_s, alpha)   # (s, s), (s,)
 
         # --- redundant local phase: s sequential scalar solves ----------
         thetas = sstep_dcd_inner(G0, u_dot_alpha, alpha[idx_s], idx_s,
@@ -154,29 +141,26 @@ def make_sstep_dcd_round_fn(A: jnp.ndarray, y: jnp.ndarray, cfg: SVMConfig,
     return round_fn
 
 
-# repro: noqa[CHK-STATIC] gram_fn/op_factory are module-level functions
+# repro: noqa[CHK-STATIC] op_factory is a module-level class or function
 #   (or None) at every call site; passing a fresh closure retraces by
-#   design — it is the documented parity-oracle escape hatch.
-@partial(jax.jit, static_argnames=("cfg", "s", "record_rounds", "gram_fn",
-                                   "op_factory"))
+#   design — it is how tests swap in a reference operator.
+@partial(jax.jit, static_argnames=("cfg", "s", "record_rounds", "op_factory"))
 def sstep_dcd_ksvm(A: jnp.ndarray, y: jnp.ndarray, alpha0: jnp.ndarray,
                    schedule: jnp.ndarray, cfg: SVMConfig, s: int,
                    record_rounds: bool = False,
-                   gram_fn: Optional[Callable] = None,
                    op_factory: Optional[Callable] = None,
                    op=None,
                    ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Run Algorithm 2 over ``ceil(H/s)`` rounds (ragged tails allowed).
 
     ``op_factory(Atil, kernel_cfg)`` overrides the slab-free GramOperator
-    (e.g. with the Pallas KMV backend from ``repro.kernels.ops`` or the
-    all-reduce operator from ``core.distributed``).  ``gram_fn(Atil, rows,
-    kernel_cfg)`` instead selects the materialized-slab path.  ``op``
-    (a pytree — crosses the jit boundary as data) injects a prebuilt,
+    (e.g. with the Pallas KMV backend from ``repro.kernels.ops``, the
+    all-reduce operator from ``core.distributed`` or the tests' slab
+    reference from ``repro.kernels.ref``).  ``op`` (a pytree — crosses the jit boundary as data) injects a prebuilt,
     already row-scaled training operator; see ``make_sstep_dcd_round_fn``.
     """
-    round_fn = make_sstep_dcd_round_fn(A, y, cfg, s, gram_fn=gram_fn,
-                                       op_factory=op_factory, op=op)
+    round_fn = make_sstep_dcd_round_fn(A, y, cfg, s, op_factory=op_factory,
+                                       op=op)
     xs = pad_rounds(schedule, s)
     res = run_rounds(round_fn, alpha0, xs, record_state=record_rounds)
     return res.state, (res.state_hist if record_rounds else None)

@@ -13,8 +13,8 @@ norms) on the VPU while the f32 accumulator tile is VMEM-resident, then
 immediately contracts the finished ``(bm x br)`` kernel tile against the
 matching ``(c x bm)`` X^T tile (second MXU op) into a ``(c x br)`` VMEM
 accumulator.  HBM traffic per round: read A once, read B, read X — zero
-slab bytes (the ``2 * m * s*b`` word round-trip of the materialized path
-disappears; see ``core.perf_model.kmv_round_hbm_bytes``).
+slab bytes (the ``2 * m * s*b`` word round-trip of a materialized slab
+disappears).
 
 Grid: (r/br, m/bm, n/bk) = (j, i, k); j parallel, i and k arbitrary so the
 (c x br) output block stays resident across the whole (i, k) sweep.

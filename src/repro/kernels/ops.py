@@ -107,20 +107,6 @@ def sdpa_flash(q, k, v, causal=True, interpret=None, bq=256, bk=256):
     return o.reshape(B, H, S, hdv).transpose(0, 2, 1, 3)
 
 
-def make_solver_gram_fn(use_pallas: bool = True):
-    """gram_fn for the core solvers' MATERIALIZED-slab path (matches
-    core.kernels.gram_slab's signature).  On non-TPU backends interpret
-    mode is slow, so solvers default to the jnp path there unless
-    explicitly forced."""
-    if not use_pallas:
-        return None
-
-    def fn(A, B, cfg):
-        return gram(A, B, cfg).astype(A.dtype)
-
-    return fn
-
-
 def make_solver_op_factory(use_pallas: bool = True, interpret=None,
                            **tiles):
     """op_factory for the core solvers: a slab-free ``GramOperator`` whose

@@ -1,9 +1,30 @@
-"""Pure-jnp oracles for every Pallas kernel in this package."""
+"""Pure-jnp oracles for every Pallas kernel in this package, and the
+materialized-slab reference operator the solver tests compare against."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 from repro.core.kernels import KernelConfig, gram_slab
+
+
+class SlabGramOperator:
+    """Reference ``GramOperator`` that MATERIALIZES the m x r slab
+    ``U = gram(A, A[idx])`` every round: ``round_data`` returns the
+    sampled block ``U[idx]`` and ``U^T x`` from it.  The tests pass it as
+    a solver's ``op_factory`` (``partial(SlabGramOperator, gram=...)``
+    swaps in another gram, e.g. the Pallas one) to check the slab-free
+    operators against the paper's formulation.  Like
+    ``AllreduceGramOperator`` it implements only ``round_data``."""
+
+    def __init__(self, A, cfg: KernelConfig, gram=gram_slab):
+        self.A, self.cfg, self.gram = A, cfg, gram
+
+    def for_rounds(self):
+        return self
+
+    def round_data(self, idx, x):
+        U = self.gram(self.A, self.A[idx], self.cfg)
+        return U[idx, :], U.T @ x
 
 
 def gram_ref(A: jnp.ndarray, B: jnp.ndarray, cfg: KernelConfig,

@@ -201,10 +201,6 @@ def solve_fleet(A, y, *, lams=None, Cs=None, kernel=None, loss: str = "l1",
     if np.any(values <= 0.0):
         raise ValueError("regularization values must be positive")
     opts = options or SolverOptions()
-    if not opts.slab_free:
-        raise ValueError("fleets are slab-free by construction "
-                         "(one shared operator); slab_free=False is the "
-                         "single-solve parity oracle")
 
     m, n = A.shape
     F = values.size

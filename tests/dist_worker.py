@@ -30,21 +30,18 @@ def main():
     failures = []
 
     # ---- K-SVM: serial DCD vs distributed s-step DCD (1D layout) ----
-    # slab_free=True (default, fused-psum GramOperator) and =False (legacy
-    # materialized-slab all-reduce) must BOTH match the serial solver.
+    # the fused-psum AllreduceGramOperator must match the serial solver.
     A, y = classification_dataset(jax.random.key(0), m=64, n=32)
     cfg = SVMConfig(C=1.0, loss="l1", kernel=KernelConfig("rbf"))
     sched = coordinate_schedule(jax.random.key(1), 32, 64)
     a0 = jnp.zeros(64)
     ref, _ = dcd_ksvm(A, y, a0, sched, cfg)
     for s in (1, 4, 16):
-        for sf in (True, False):
-            got = dist_sstep_dcd_ksvm(mesh, A, y, a0, sched, cfg, s=s,
-                                      slab_free=sf)
-            err = float(jnp.max(jnp.abs(got - ref)))
-            print(f"dcd s={s} slab_free={sf} maxdiff={err:.3e}")
-            if err > 5e-5:
-                failures.append(f"dcd s={s} slab_free={sf}")
+        got = dist_sstep_dcd_ksvm(mesh, A, y, a0, sched, cfg, s=s)
+        err = float(jnp.max(jnp.abs(got - ref)))
+        print(f"dcd s={s} maxdiff={err:.3e}")
+        if err > 5e-5:
+            failures.append(f"dcd s={s}")
     got = dist_dcd_ksvm(mesh, A, y, a0, sched, cfg)
     if float(jnp.max(jnp.abs(got - ref))) > 5e-5:
         failures.append("dcd classical")
@@ -81,13 +78,11 @@ def main():
     bsched = block_schedule(jax.random.key(3), 16, 64, 4)
     ref, _ = bdcd_krr(A, y, a0, bsched, kcfg)
     for s in (1, 4):
-        for sf in (True, False):
-            got = dist_sstep_bdcd_krr(mesh, A, y, a0, bsched, kcfg, s=s,
-                                      slab_free=sf)
-            err = float(jnp.max(jnp.abs(got - ref)))
-            print(f"bdcd-1d s={s} slab_free={sf} maxdiff={err:.3e}")
-            if err > 5e-5:
-                failures.append(f"bdcd1d s={s} slab_free={sf}")
+        got = dist_sstep_bdcd_krr(mesh, A, y, a0, bsched, kcfg, s=s)
+        err = float(jnp.max(jnp.abs(got - ref)))
+        print(f"bdcd-1d s={s} maxdiff={err:.3e}")
+        if err > 5e-5:
+            failures.append(f"bdcd1d s={s}")
         got2 = dist_sstep_bdcd_krr_2d(mesh, A, y, a0, bsched, kcfg, s=s)
         err2 = float(jnp.max(jnp.abs(got2 - ref)))
         print(f"bdcd-2d s={s} maxdiff={err2:.3e}")

@@ -97,10 +97,10 @@ def test_tracer_branch_caught():
 
 def test_tracer_static_tests_allowed():
     src = textwrap.dedent("""
-        def make_foo_round_fn(A, gram_fn=None):
+        def make_foo_round_fn(A, op_factory=None):
             def round_fn(alpha, xs):
-                if gram_fn is not None:
-                    alpha = gram_fn(alpha)
+                if op_factory is not None:
+                    alpha = op_factory(alpha)
                 if A.ndim == 2 and len(xs) > 1:
                     alpha = alpha + 1
                 return alpha
@@ -111,8 +111,8 @@ def test_tracer_static_tests_allowed():
 
 def test_static_callable_argname_caught():
     src = textwrap.dedent("""
-        @functools.partial(jax.jit, static_argnames=("gram_fn",))
-        def solve(A, gram_fn: Optional[Callable] = None):
+        @functools.partial(jax.jit, static_argnames=("op_factory",))
+        def solve(A, op_factory: Optional[Callable] = None):
             return A
     """)
     found = lint._check_static("<fx>", ast.parse(src))

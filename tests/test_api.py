@@ -88,13 +88,15 @@ def test_krr_matches_legacy(krr_data, kernel, method, layout):
 
 
 def test_slab_free_false_matches_materialized_oracle(svm_data):
+    """The facade's fit == the entry point on the materialized-slab
+    reference operator, on the fit's own schedule."""
     A, y = svm_data
-    opts = SolverOptions(method="sstep", s=S, max_iters=H, slab_free=False)
+    opts = SolverOptions(method="sstep", s=S, max_iters=H)
     res = KernelSVM(kernel="rbf", options=opts).fit(A, y)
-    from repro.core import gram_slab
+    from repro.kernels.ref import SlabGramOperator
     ref, _ = sstep_dcd_ksvm(A, y, jnp.zeros(M), res.schedule,
                             KernelSVM(kernel="rbf").cfg, s=S,
-                            gram_fn=gram_slab)
+                            op_factory=SlabGramOperator)
     np.testing.assert_allclose(np.asarray(res.alpha), np.asarray(ref),
                                **TOL)
 
@@ -163,7 +165,6 @@ def test_record_without_tol_runs_full_budget(krr_data):
     dict(check_every=0),
     dict(tol=-1e-3),
     dict(tol=float("nan")),
-    dict(layout="2d", slab_free=False),
 ], ids=lambda d: ",".join(f"{k}={v}" for k, v in d.items()))
 def test_solver_options_validate_eagerly(bad):
     with pytest.raises(ValueError):

@@ -115,7 +115,7 @@ def test_guarded_sstep_stability_matrix(problem, s):
 
     key = jax.random.key(20)
     H = 512
-    opts = dict(max_iters=H, seed=5, slab_free=True)
+    opts = dict(max_iters=H, seed=5)
     # cadence 1: s=256 leaves only ceil(512/256)=2 outer rounds, so the
     # correction must fire every round to be exercised at every s
     guard = dict(guard=True, recompute_every=1)

@@ -138,15 +138,16 @@ def test_kmv_pallas_operator_end_to_end():
     """s-step DCD driven by the Pallas-KMV GramOperator backend == the
     materialized-slab solver (kernels.ops.make_solver_op_factory path)."""
     from repro.core import SVMConfig, coordinate_schedule, sstep_dcd_ksvm
-    from repro.core.kernels import gram_slab as gs
     from repro.data.synthetic import classification_dataset
     from repro.kernels.ops import make_solver_op_factory
+    from repro.kernels.ref import SlabGramOperator
 
     A, y = classification_dataset(jax.random.key(1), m=48, n=32)
     cfg = SVMConfig(C=1.0, loss="l2", kernel=KernelConfig("rbf"))
     sched = coordinate_schedule(jax.random.key(2), 16, 48)
     a0 = jnp.zeros(48)
-    ref, _ = sstep_dcd_ksvm(A, y, a0, sched, cfg, s=8, gram_fn=gs)
+    ref, _ = sstep_dcd_ksvm(A, y, a0, sched, cfg, s=8,
+                            op_factory=SlabGramOperator)
     factory = make_solver_op_factory(use_pallas=True, interpret=True,
                                      bm=16, br=8, bk=128)
     got, _ = sstep_dcd_ksvm(A, y, a0, sched, cfg, s=8, op_factory=factory)

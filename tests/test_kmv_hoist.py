@@ -77,7 +77,7 @@ def _solve_jaxpr(case, m):
     if case == "tol":
         return jax.make_jaxpr(lambda op: _krr_serial_tol(
             A, y, a0, bsched, jnp.asarray(1e-6), cfg=krr, s=4,
-            check_every=2, slab_free=True, op=op))(op)
+            check_every=2, op=op))(op)
     assert case == "fleet"
     return jax.make_jaxpr(lambda op: _fleet_serial(
         A, y, jnp.zeros((2, m)), jnp.asarray([0.5, 1.0]), bsched,

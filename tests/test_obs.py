@@ -217,7 +217,7 @@ def _tol_args():
     A, y, a0 = _small()
     cfg = KRRConfig(lam=1.0, kernel=KernelConfig("rbf"))
     return (A, y, a0, jnp.zeros((8, 2), jnp.int32), jnp.asarray(1e-6)), \
-        dict(cfg=cfg, s=4, check_every=2, slab_free=True,
+        dict(cfg=cfg, s=4, check_every=2,
              op=ExactGramOperator(A, cfg.kernel))
 
 
@@ -270,7 +270,7 @@ for layout, shape in (("1d", (1, 4)), ("2d", (2, 2))):
             ("ksvm", SVMConfig(), jnp.zeros(16, jnp.int32)),
             ("krr", KRRConfig(), jnp.zeros((8, 2), jnp.int32))):
         f = jax.jit(lambda A, y, a0, sc: _dist_call(
-            problem, layout, mesh, A, y, a0, sc, cfg, 4, True))
+            problem, layout, mesh, A, y, a0, sc, cfg, 4))
         text = f.lower(A, y, jnp.zeros(32), sched).compile().as_text()
         out[f"{layout}.{problem}"] = sorted(set(
             re.findall(r'op_name="([^"]*)"', text)))

@@ -75,9 +75,13 @@ def test_gram_rbf_diagonal_is_one():
 
 
 def test_solver_with_pallas_gram_matches_jnp_gram():
-    """End-to-end: s-step DCD with the Pallas slab == with the jnp slab."""
+    """End-to-end: s-step DCD on the reference slab operator built by the
+    Pallas gram == on the default (jnp) operator."""
+    from functools import partial
+
     from repro.core import (KernelConfig, SVMConfig, coordinate_schedule,
                             sstep_dcd_ksvm)
+    from repro.kernels.ref import SlabGramOperator
     from repro.data.synthetic import classification_dataset
 
     A, y = classification_dataset(jax.random.key(1), m=48, n=32)
@@ -90,6 +94,8 @@ def test_solver_with_pallas_gram_matches_jnp_gram():
         return gram_pallas(Am, Bm, kcfg, bm=16, br=16, bk=128,
                            interpret=True).astype(Am.dtype)
 
-    got, _ = sstep_dcd_ksvm(A, y, a0, sched, cfg, s=8, gram_fn=pallas_gram)
+    got, _ = sstep_dcd_ksvm(A, y, a0, sched, cfg, s=8,
+                            op_factory=partial(SlabGramOperator,
+                                               gram=pallas_gram))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)

@@ -34,8 +34,7 @@ def _data(m=192, n=12, seed=0):
 
 
 def _opts(**kw):
-    base = dict(method="sstep", s=8, max_iters=384, seed=3,
-                slab_free=True)
+    base = dict(method="sstep", s=8, max_iters=384, seed=3)
     base.update(kw)
     return SolverOptions(**base)
 
@@ -272,8 +271,7 @@ from repro.resilience import FaultPlan, inject
 rng = np.random.default_rng(0)
 A = jnp.asarray(rng.standard_normal((128, 16)), jnp.float32)
 y = jnp.asarray(A @ rng.standard_normal(16) + 0.1, jnp.float32)
-kw = dict(method="sstep", s=8, b=8, max_iters=256, seed=3, layout="1d",
-          slab_free=True)
+kw = dict(method="sstep", s=8, b=8, max_iters=256, seed=3, layout="1d")
 plain = KernelRidge(lam=0.5, kernel="linear",
                     options=SolverOptions(**kw)).fit(A, y)
 guard = KernelRidge(lam=0.5, kernel="linear",

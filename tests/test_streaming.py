@@ -203,8 +203,6 @@ def test_stream_options_validated():
     with pytest.raises(ValueError):
         SolverOptions(stream=0)
     with pytest.raises(ValueError):
-        SolverOptions(stream=16, slab_free=False)
-    with pytest.raises(ValueError):
         SolverOptions(stream=16, layout="1d")
     with pytest.raises(ValueError):
         SolverOptions(stream=16, approx="nystrom")

@@ -105,8 +105,6 @@ def test_fleet_input_validation(krr_data):
         solve_fleet(A, y)
     with pytest.raises(ValueError, match="positive"):
         solve_fleet(A, y, lams=[1.0, -2.0])
-    with pytest.raises(ValueError, match="slab-free"):
-        solve_fleet(A, y, lams=LAMS, options=_opts(slab_free=False))
     with pytest.raises(ValueError, match="fleet layout"):
         solve_fleet(A, y, lams=LAMS, options=_opts(layout="2d"))
 
