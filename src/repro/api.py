@@ -1006,8 +1006,9 @@ def _fit_body(problem: str, A, y, cfg, opts: SolverOptions, *,
     rep_op, A_s = rep
     cfg_s = _solve_cfg(cfg, opts)
     # like representation_build, the schedule and train_op spans block
-    # on their outputs (telemetry on only), so each times its own work
-    with _tspan(tel, "schedule", "setup"):
+    # on their outputs (telemetry on only), so each times its own work;
+    # the schedule span also counts the programs its draw built
+    with _tspan(tel, "schedule", "setup", count_compiles=True):
         schedule = (coordinate_schedule(key, H, m) if problem == "ksvm"
                     else block_schedule(key, H, m, b))
         if tel is not None:

@@ -40,6 +40,7 @@ class KRRConfig:
     kernel: KernelConfig = dataclasses.field(default_factory=KernelConfig)
 
 
+@partial(jax.jit, static_argnames=("H", "m", "b"))
 def block_schedule(key: jax.Array, H: int, m: int, b: int) -> jnp.ndarray:
     """(H, b) coordinate blocks, each sampled uniformly WITHOUT replacement
     (paper Alg. 3 line 4). Shared by BDCD and s-step BDCD.
@@ -48,7 +49,11 @@ def block_schedule(key: jax.Array, H: int, m: int, b: int) -> jnp.ndarray:
     t uniform on [0, m-b+j] and keeps it, or m-b+j if t is already taken,
     which makes every b-subset equally likely.  ``jax.random.choice``
     would permute all m indices per block, an (H, m) sort that does not
-    fit a chip's memory at m=2^18, H=4096."""
+    fit a chip's memory at m=2^18, H=4096.
+
+    The draw is one cached program per (H, m, b), with the key traced:
+    a fit at a shape seen before builds nothing.  The range check on b
+    runs while tracing, so a bad b raises before anything compiles."""
     if not 1 <= b <= m:
         raise ValueError(f"block size b={b} must be in [1, m={m}]")
     keys = jax.random.split(key, H)

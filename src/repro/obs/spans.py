@@ -23,10 +23,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import threading
 import time
 from typing import Any, Dict, List, Optional
 
 import jax
+import jax.monitoring
 
 
 @dataclasses.dataclass
@@ -56,6 +58,29 @@ class Mark:
     value: Optional[float] = None
 
 
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def programs_built(out: Dict[str, Any]):
+    """Count into ``out["compiles"]`` the XLA programs the calling thread
+    builds while the with-body runs: ``jax.monitoring``'s backend-compile
+    event, which fires once per program compiled or loaded from the
+    persistent cache, and never for a program jit already holds."""
+    tid = threading.get_ident()
+    out["compiles"] = 0
+
+    def on_event(event, _secs, **_):
+        if event == _BACKEND_COMPILE and threading.get_ident() == tid:
+            out["compiles"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        yield out
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+
+
 class Telemetry:
     """The recording handle ``SolverOptions(telemetry=...)`` and
     ``ServingEngine(telemetry=...)`` accept (DESIGN.md §15).
@@ -73,21 +98,25 @@ class Telemetry:
         self.metrics = MetricsRegistry() if metrics is None else metrics
 
     @contextlib.contextmanager
-    def span(self, name: str, phase: str = "host", **args):
+    def span(self, name: str, phase: str = "host", *,
+             count_compiles: bool = False, **args):
         """Record a closed host span around the with-body, inside a
         profiler annotation ``repro.<name>`` (no-op when disabled).
         The span is appended at EXIT, so the log stays ordered by end
-        time."""
+        time.  ``count_compiles=True`` adds the arg ``compiles``: the
+        XLA programs this thread built in the body (``programs_built``)."""
         if not self.enabled:
             yield None
             return
-        with jax.profiler.TraceAnnotation(f"repro.{name}"):
+        built = (programs_built(args) if count_compiles
+                 else contextlib.nullcontext())
+        with jax.profiler.TraceAnnotation(f"repro.{name}"), built:
             t0 = time.perf_counter()
             try:
                 yield None
             finally:
                 self.spans.append(Span(name, phase, t0,
-                                       time.perf_counter(), dict(args)))
+                                       time.perf_counter(), args))
 
     def mark(self, name: str, phase: str = "host", value=None) -> None:
         """Record one instant event (no-op when disabled)."""
